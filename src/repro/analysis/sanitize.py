@@ -18,6 +18,10 @@ Two counters, two purposes:
   cache (the engine's ``_jit_cache``). Per-engine, survives unrelated
   compiles elsewhere in the process; what ``engine.compiled_programs()``
   delegates to.
+
+The same listener counts persistent compilation-cache hits
+(:func:`persistent_cache_hits`), which ``chip_smoke.py`` reports per
+phase.
 """
 
 from __future__ import annotations
@@ -25,10 +29,12 @@ from __future__ import annotations
 import threading
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 _lock = threading.Lock()
 _installed = False
 _count = 0
+_cache_hits = 0
 
 
 def _on_event(event: str, duration: float, **kwargs) -> None:
@@ -36,6 +42,13 @@ def _on_event(event: str, duration: float, **kwargs) -> None:
     if event == _COMPILE_EVENT:
         with _lock:
             _count += 1
+
+
+def _on_cache_event(event: str, **kwargs) -> None:
+    global _cache_hits
+    if event == _CACHE_HIT_EVENT:
+        with _lock:
+            _cache_hits += 1
 
 
 def install_compile_listener() -> None:
@@ -49,6 +62,7 @@ def install_compile_listener() -> None:
         _installed = True
     from jax import monitoring
     monitoring.register_event_duration_secs_listener(_on_event)
+    monitoring.register_event_listener(_on_cache_event)
 
 
 def total_backend_compiles() -> int:
@@ -59,16 +73,17 @@ def total_backend_compiles() -> int:
     return _count
 
 
+def persistent_cache_hits() -> int:
+    """Programs loaded from the persistent compilation cache since the
+    listener was installed."""
+    install_compile_listener()
+    return _cache_hits
+
+
 def jit_cache_programs(fns) -> int:
     """Total traced programs across an iterable of jitted callables (an
     engine's ``_jit_cache.values()``)."""
-    total = 0
-    for fn in fns:
-        try:
-            total += fn._cache_size()
-        except Exception:  # pragma: no cover — older jax without _cache_size
-            total += 1
-    return total
+    return sum(fn._cache_size() for fn in fns)
 
 
 class RecompileError(RuntimeError):

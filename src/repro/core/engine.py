@@ -377,9 +377,13 @@ class SpecDecodeEngine:
         return jitted
 
     def _split_step(self, gamma_max: int):
-        """SSM/hybrid-target path: verify on a throwaway cache, then advance
-        the committed prefix with an active-masked ``lax.scan``. Per-slot
-        stopping composes naturally: the advance is masked by the *stopped*
+        """Path for pairs with a recurrent (SSM/hybrid) side: a recurrent
+        side's state cannot be masked retroactively, so it is verified (or
+        drafted) on a throwaway copy and then advanced over the committed
+        prefix with an active-masked ``lax.scan``. An attention target
+        keeps its verify-pass cache instead (``pos_map`` masks the stale
+        window tail), as the split TargetWorker does. Per-slot stopping
+        composes naturally: the advance is masked by the *stopped*
         ``num_new``, so a finished/free row's recurrent state (and hybrid
         shared-attention cache) never advances."""
         keyt = ("split", gamma_max)
@@ -397,7 +401,7 @@ class SpecDecodeEngine:
                                  state.pos, gamma_max, kd, self.temperature)
             window = jnp.concatenate(
                 [state.last_token[:, None], prop.tokens], axis=1)
-            p_logits, _discard = self.target.verify_step(
+            p_logits, tcache_spec = self.target.verify_step(
                 target_params, window, state.target_cache, state.pos)
             if self.temperature <= 0.0:
                 res = verify_window_greedy(prop.tokens, p_logits,
@@ -423,9 +427,12 @@ class SpecDecodeEngine:
             # we advance exactly num_new tokens starting from last_token.
             adv_tokens = jnp.concatenate(
                 [state.last_token[:, None], committed[:, :gamma_max]], axis=1)
-            tcache = _scan_cache_advance(
-                self.target.decode_step, target_params, state.target_cache,
-                adv_tokens, state.pos, stop.num_new)
+            if self._target_attention:
+                tcache = tcache_spec
+            else:
+                tcache = _scan_cache_advance(
+                    self.target.decode_step, target_params,
+                    state.target_cache, adv_tokens, state.pos, stop.num_new)
 
             dcache = prop.cache
             if not self._draft_attention:

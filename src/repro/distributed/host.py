@@ -50,6 +50,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import glob
 import json
 import os
 import select
@@ -59,6 +60,7 @@ import sys
 import time
 from contextlib import nullcontext
 from dataclasses import dataclass
+from typing import Optional
 
 import numpy as np
 
@@ -100,7 +102,8 @@ def load_params(cfg, path: str):
     import jax
 
     from ..models.model import build_model
-    template = build_model(cfg).init_params(jax.random.PRNGKey(0))
+    template = jax.eval_shape(build_model(cfg).init_params,
+                              jax.random.PRNGKey(0))
     leaves, treedef = jax.tree.flatten(template)
     with np.load(path) as z:
         loaded = [z[f"leaf_{i}"] for i in range(len(leaves))]
@@ -108,8 +111,7 @@ def load_params(cfg, path: str):
         raise ValueError(f"param file {path} has {len(loaded)} leaves, "
                          f"config expects {len(leaves)}")
     return jax.tree.unflatten(treedef, [
-        np.asarray(a, dtype=np.asarray(t).dtype)
-        for a, t in zip(loaded, leaves)])
+        np.asarray(a, dtype=t.dtype) for a, t in zip(loaded, leaves)])
 
 
 # --------------------------------------------------------------------------
@@ -142,6 +144,11 @@ class _HostContext:
         else:
             raise TopologyError(f"unknown pair id {args.pair!r}")
         validate_process_pair(self.spec, self.pair)
+        if self.spec.full_width:
+            # the workers of a deployment compile the same full-width
+            # programs: share them, and keep them for the next run
+            from ..launch.compile_cache import enable_compile_cache
+            enable_compile_cache()
         self.model_configs = {}
         for name, path in _parse_kv(args.model_config).items():
             self.model_configs[name] = load_model_config(path)
@@ -159,39 +166,19 @@ class _HostContext:
     def build_engine(self):
         import jax
 
-        from ..configs import get_config
         from ..core.engine import SpecDecodeEngine
         from ..models.model import build_model
+        from ..topology import node_key, resolve_node_configs
         spec, s = self.spec, self.spec.serving
-
-        def resolve(node):
-            if node.model in self.model_configs:
-                return self.model_configs[node.model]
-            return get_config(node.model).reduced()
-
-        raw = {n.id: resolve(n) for n in spec.nodes}
-        vocab = min(c.vocab for c in raw.values())
-        configs = {nid: (c if c.vocab == vocab
-                         else dataclasses.replace(c, vocab=vocab))
-                   for nid, c in raw.items()}
-
-        kd, kt = jax.random.split(jax.random.PRNGKey(spec.seed))
-        need = {self.pair.draft, self.pair.target}
+        configs, _vocab = resolve_node_configs(spec, self.model_configs)
         params = {}
-        role_index = {"draft": 0, "target": 0}
-        for n in spec.nodes:         # full sweep: role indices must match
-            i = role_index[n.role]   # build_deployment's numbering exactly
-            role_index[n.role] += 1
-            if n.id not in need:
-                continue
-            if n.id in self.node_param_paths:
-                params[n.id] = load_params(configs[n.id],
-                                           self.node_param_paths[n.id])
-                continue
-            k = kd if n.role == "draft" else kt
-            if i > 0:
-                k = jax.random.fold_in(k, i)
-            params[n.id] = build_model(configs[n.id]).init_params(k)
+        for nid in (self.pair.draft, self.pair.target):
+            if nid in self.node_param_paths:
+                params[nid] = load_params(configs[nid],
+                                          self.node_param_paths[nid])
+            else:
+                params[nid] = build_model(configs[nid]).init_params(
+                    node_key(spec, nid))
 
         self.engine = SpecDecodeEngine(
             configs[self.pair.draft], configs[self.pair.target],
@@ -716,15 +703,96 @@ class PairHostHandle:
     close = shutdown
 
 
+def holds_tpu() -> bool:
+    """True when this process has already brought up JAX's TPU backend
+    (checked without initializing any backend)."""
+    from jax._src import xla_bridge
+    return (xla_bridge.backends_are_initialized()
+            and "tpu" in xla_bridge._backends)
+
+
+def host_tpu_chips() -> int:
+    """TPU chips a worker process started from here could open, counted
+    without initializing JAX: none when ``JAX_PLATFORMS`` keeps JAX off
+    the TPU or the PCI bus has no TPU; otherwise the chip device files
+    (``/dev/accel*``, or ``/dev/vfio/<group>``) — a machine handed only
+    some of its host's chips lists all of them on the bus but opens only
+    its own."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return 0
+    from jax._src import hardware_utils
+    on_bus = hardware_utils.num_available_tpu_chips_and_device_id()[0]
+    if on_bus == 0:
+        return 0
+    files = len(glob.glob("/dev/accel[0-9]*"))
+    if not files and os.path.isdir("/dev/vfio"):
+        files = sum(name.isdigit() for name in os.listdir("/dev/vfio"))
+    return min(on_bus, files) if files else on_bus
+
+
+def check_worker_chips(n_workers: int, first_chip: int = 0) -> int:
+    """Enforce one process per chip before any worker starts: a chip
+    belongs to one process, so on a TPU host the caller must not hold the
+    TPU itself and every worker needs a chip of its own (workers
+    ``first_chip .. first_chip + n_workers - 1``). Raises
+    :class:`repro.topology.TopologyError` at once instead of letting the
+    handshake wait out its timeout. Returns the host's chip count (0 =
+    the workers run on the CPU)."""
+    from ..topology import TopologyError
+    chips = host_tpu_chips()
+    if chips == 0:
+        return 0
+    if holds_tpu():
+        raise TopologyError(
+            "one process per chip: this process already holds the TPU "
+            "backend, so worker processes cannot get a chip. Build "
+            "process-backed deployments before touching JAX, or serve the "
+            "pairs in-process")
+    if first_chip + n_workers > chips:
+        raise TopologyError(
+            f"one process per chip: process-backed pairs need one TPU chip "
+            f"per worker process ({first_chip + n_workers} wanted), and "
+            f"this host has {chips}")
+    return chips
+
+
+def _free_port() -> int:
+    with socket.socket(socket.AF_INET, socket.SOCK_STREAM) as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+def _bind_chip(env: dict, chip: int) -> dict:
+    """Environment that binds one worker process to TPU chip ``chip`` as a
+    one-chip slice of its own (the chips-per-process bounds are a subset
+    of the host, which libtpu accepts as one load per chip)."""
+    port = _free_port()
+    return dict(env, TPU_VISIBLE_CHIPS=str(chip),
+                TPU_CHIPS_PER_PROCESS_BOUNDS="1,1,1",
+                TPU_PROCESS_BOUNDS="1,1,1",
+                TPU_PROCESS_PORT=str(port),
+                TPU_PROCESS_ADDRESSES=f"localhost:{port}")
+
+
 def spawn_pair(spec, pair, *, model_configs=None, node_params=None,
-               workdir=None, timeout_s: float = 120.0,
-               python: str = sys.executable) -> PairHostHandle:
+               workdir=None, timeout_s: Optional[float] = None,
+               python: str = sys.executable,
+               first_chip: int = 0) -> PairHostHandle:
     """Launch a target host + draft host for one ``process: true`` pair
     on localhost and hand back the driving handle. Topology, overridden
     model configs and overridden node params are written to ``workdir``
-    and shipped by path; everything else rebuilds from the spec's seed."""
+    and shipped by path; everything else rebuilds from the spec's seed.
+    On a TPU host the target runs on chip ``first_chip`` and the draft on
+    the next one (:func:`check_worker_chips` refuses first if that cannot
+    be had). ``timeout_s`` bounds every socket wait; by default 120 s,
+    or 600 s for a full-width spec, whose hosts first build and compile
+    full-size models."""
     import tempfile
     validate_process_pair(spec, pair)
+    if timeout_s is None:
+        timeout_s = 600.0 if spec.full_width else 120.0
+    tpu_host = check_worker_chips(2, first_chip) > 0
     workdir = workdir or tempfile.mkdtemp(prefix=f"dsd-{pair.id}-")
     os.makedirs(workdir, exist_ok=True)
     topo_path = os.path.join(workdir, "topology.json")
@@ -747,22 +815,24 @@ def spawn_pair(spec, pair, *, model_configs=None, node_params=None,
     prev = env.get("PYTHONPATH", "")
     env["PYTHONPATH"] = src_dir + (os.pathsep + prev if prev else "")
 
-    def launch(role, extra):
+    def launch(role, extra, chip):
         err = open(os.path.join(workdir, f"{role}.stderr.log"), "wb")
         return subprocess.Popen(
             [python, "-m", "repro.distributed.host", "--role", role,
              "--topology", topo_path, "--pair", pair.id,
              "--timeout-s", str(timeout_s)] + cfg_flags + extra,
-            stdout=subprocess.PIPE, stderr=err, env=env)
+            stdout=subprocess.PIPE, stderr=err,
+            env=_bind_chip(env, chip) if tpu_host else env)
 
     procs = []
     try:
-        tgt = launch("target", [])
+        tgt = launch("target", [], first_chip)
         procs.append(tgt)
         line = _read_line(tgt, "listening port=", 60.0,
                           f"target host ({pair.id})")
         t_port = int(line.split("=", 1)[1])
-        drf = launch("draft", ["--connect", f"127.0.0.1:{t_port}"])
+        drf = launch("draft", ["--connect", f"127.0.0.1:{t_port}"],
+                     first_chip + 1)
         procs.append(drf)
         line = _read_line(drf, "listening port=", 60.0,
                           f"draft host ({pair.id})")

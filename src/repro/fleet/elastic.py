@@ -61,6 +61,7 @@ class ElasticPairPool:
         # pair_id -> handle / state ("idle" | "busy" | "draining")
         self.handles: dict[str, object] = {}
         self._state: dict[str, str] = {}
+        self._chip_slot: dict[str, int] = {}   # pair id -> chip pair index
         self.events: list[tuple[float, str, str]] = []   # (t, kind, pair_id)
         self.results: list = []
         self._served: dict[str, int] = {}
@@ -72,7 +73,18 @@ class ElasticPairPool:
     def _default_spawn(self, pair_spec):
         from ..distributed.host import spawn_pair
         spec = dataclasses.replace(self.spec, pairs=[pair_spec])
-        return spawn_pair(spec, pair_spec, model_configs=self.model_configs)
+        # on a TPU host each live pair holds two chips: take the lowest
+        # free pair of chips (spawn_pair refuses when none is left)
+        slot = min(set(range(len(self._chip_slot) + 1))
+                   - set(self._chip_slot.values()))
+        handle = spawn_pair(spec, pair_spec, model_configs=self.model_configs,
+                            first_chip=2 * slot)
+        self._chip_slot[pair_spec.id] = slot
+        return handle
+
+    def _shutdown_pair(self, pid: str) -> None:
+        self.handles[pid].shutdown()
+        self._chip_slot.pop(pid, None)
 
     def _now(self) -> float:
         return time.perf_counter() - self._t0
@@ -106,7 +118,7 @@ class ElasticPairPool:
     def _finalize_drained(self) -> None:
         for pid, st in list(self._state.items()):
             if st == "draining":
-                self.handles[pid].shutdown()
+                self._shutdown_pair(pid)
                 del self._state[pid]
 
     # -- control law ---------------------------------------------------------
@@ -188,7 +200,7 @@ class ElasticPairPool:
             for pid, st in list(self._state.items()):
                 if st == "draining" and (pid not in threads
                                          or not threads[pid].is_alive()):
-                    self.handles[pid].shutdown()
+                    self._shutdown_pair(pid)
                     del self._state[pid]
             time.sleep(self.tick_s)
         for t in threads.values():

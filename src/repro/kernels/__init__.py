@@ -1,20 +1,23 @@
-"""Pallas TPU kernels (validated in interpret mode on CPU):
+"""Pallas TPU kernels. Interpret mode runs them on the CPU for the tests;
+on a TPU backend they always compile through Mosaic.
 
-- verify       — fused speculative-window verification (vocab-tiled)
-- decode_attn  — GQA flash-decode over KV caches (+sliding window/ring)
-- ssd          — Mamba2/SSD chunked scan
+- decode_attn/paged — paged GQA flash-decode; the served path's attention
+  for paged sessions on TPU (``models.attention.attention_decode_paged``)
+- verify/tree       — fused greedy tree verify (opt-in,
+  ``SpecDecodeEngine(use_verify_kernel=True)``)
+- verify/verify, decode_attn/decode_attn, ssd — linear-window verify, dense
+  flash-decode and the SSD chunked scan; off the served path
 """
 
 import functools as _functools
 
 import jax as _jax
-from jax.experimental.pallas import tpu as _pltpu
 
 
 def default_interpret() -> bool:
     """Resolve the kernels' shared ``interpret=None`` auto-default: compile
-    for real on TPU backends, fall back to the Pallas interpreter on CPU/GPU
-    (where Mosaic can't lower). Callers override per-call for A/B tests."""
+    for real on TPU backends; the Pallas interpreter elsewhere, which is
+    how the CPU tests run the kernels (Mosaic lowers for TPU only)."""
     return _jax.default_backend() != "tpu"
 
 
@@ -30,11 +33,3 @@ def kernel_op(*static_argnames):
     return _functools.partial(_jax.jit,
                               static_argnames=(*static_argnames, "interpret"))
 
-
-def tpu_compiler_params(**kwargs):
-    """Version-compat shim: newer jax exposes ``pltpu.CompilerParams``,
-    older releases call it ``TPUCompilerParams``."""
-    cls = getattr(_pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = _pltpu.TPUCompilerParams
-    return cls(**kwargs)

@@ -29,7 +29,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from typing import Optional
 
-from .. import resolve_interpret, tpu_compiler_params
+from .. import resolve_interpret
 
 S_TILE = 512
 NEG_INF = -1e30
@@ -119,7 +119,7 @@ def decode_attn_call(q: jax.Array,        # (B, T, Hkv, G, hd)
         scratch_shapes=[pltpu.VMEM((T, G), jnp.float32),
                         pltpu.VMEM((T, G), jnp.float32),
                         pltpu.VMEM((T, G, hd), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q_pos, q, k, v, pos_map)

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from .. import tpu_compiler_params
 
 CHUNK = 128
 
@@ -107,7 +106,7 @@ def ssd_call(x: jax.Array,    # (B, S, nh, hd)
             jax.ShapeDtypeStruct((B, nh, hd, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hd, N), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, Bm, Cm, dt, A, h_in)

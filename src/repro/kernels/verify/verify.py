@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from .. import resolve_interpret, tpu_compiler_params
+from .. import resolve_interpret
 
 VOCAB_TILE = 512
 
@@ -130,7 +130,7 @@ def gather_reduce_call(tokens, p, q, tile: int = VOCAB_TILE,
         ],
         out_shape=[jax.ShapeDtypeStruct((B, gamma), jnp.float32)] * 3,
         scratch_shapes=[pltpu.VMEM((gamma,), jnp.float32)] * 3,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(tokens, p, q)

@@ -22,9 +22,12 @@ behaviorally identical):
         [--mode-policy auto|distributed|fused|pipeline]
 
 ``--arrival-rate`` draws Poisson arrivals (requests/s); TTFT and e2e are
-measured from each request's arrival, so they include queue wait. Reduced-
-variant models by default (this is the host-runnable driver; the full
-configs exercise the dry-run path).
+measured from each request's arrival, so they include queue wait. Models
+run as their reduced variants (2 layers, float32) by default, which is
+what the CPU tests use; ``--full-width`` serves every node's model at its
+published widths (``ClusterSpec.full_width``), which needs the chip.
+Compiled programs persist in ``$JAX_COMPILATION_CACHE_DIR``, or in
+``<repo>/.jax_cache`` when that is unset (:mod:`repro.launch.compile_cache`).
 
 ``--link-rtt-ms`` switches the continuous server to DISTRIBUTED execution:
 speculation rounds run as real draft→verify→verdict exchanges over a
@@ -49,7 +52,9 @@ import numpy as np
 
 from ..configs import ARCHS
 from ..serving import ServeRequest, WaveSpecDecodeServer
-from ..topology import ClusterSpec, build_deployment, one_pair_spec
+from ..topology import (ClusterSpec, Deployment, build_deployment,
+                        one_pair_spec)
+from .compile_cache import enable_compile_cache
 
 
 def spec_from_args(args) -> ClusterSpec:
@@ -75,7 +80,87 @@ def spec_from_args(args) -> ClusterSpec:
         spec.workload.max_new = args.max_new
     if args.arrival_rate is not None:
         spec.workload.rate_per_s = args.arrival_rate
+    if args.full_width:
+        spec.full_width = True
     return spec.validate()
+
+
+def workload_requests(spec: ClusterSpec, vocab: int) -> list:
+    """The spec's seeded request stream: the fleet trace's class-aware
+    arrivals with per-class SLOs when the workload declares one (the SAME
+    stream build_simulation replays), else ``num_requests`` uniform prompts
+    with Poisson arrivals at ``rate_per_s`` (all at t=0 when 0)."""
+    wl = spec.workload
+    if wl.trace is not None:
+        from ..fleet.workload import fleet_serve_requests, generate_requests
+        return list(fleet_serve_requests(generate_requests(wl.trace), vocab,
+                                         seed=spec.seed))
+    rng = np.random.default_rng(spec.seed)
+    arrival = 0.0
+    reqs = []
+    for i in range(wl.num_requests):
+        plen = int(rng.integers(wl.prompt_lo, wl.prompt_hi))
+        if wl.rate_per_s > 0:
+            arrival += float(rng.exponential(1.0 / wl.rate_per_s))
+        reqs.append(ServeRequest(
+            i, rng.integers(0, vocab, plen).astype(np.int32), wl.max_new,
+            arrival_s=arrival))
+    return reqs
+
+
+def serve(spec: ClusterSpec, deployment: Deployment,
+          topology: str = "") -> tuple[list, dict]:
+    """Serve the spec's workload through ``deployment``: build the server
+    (continuous, or the wave baseline), submit :func:`workload_requests`,
+    drain it. Returns the per-request results and the summary
+    :func:`main` prints. Leaves the deployment running; the caller owns
+    :meth:`~repro.topology.Deployment.shutdown`."""
+    if spec.serving.server == "wave":
+        pair0 = deployment.pairs[0]
+        cfg = deployment.server_config()
+        # the wave baseline reads mode_policy off its ServerConfig (it has
+        # no pair objects); forward the single pair's declared mode
+        cfg.mode_policy = pair0.mode_policy
+        server = WaveSpecDecodeServer(pair0.engine, pair0.policy, cfg)
+    else:
+        server = deployment.build_server()
+    for req in workload_requests(spec, deployment.vocab):
+        server.submit(req)
+    results = server.run()
+
+    accs = [r.acceptance_rate for r in results]
+    tpots = [r.tpot_ms for r in results]
+    summary = {
+        "server": spec.serving.server,
+        "topology": topology or "one-pair(flags)",
+        "pairs_deployed": len(deployment.pairs),
+        "requests": len(results),
+        "mean_acceptance": float(np.mean(accs)),
+        "mean_ttft_ms": float(np.mean([r.ttft_ms for r in results])),
+        "mean_queue_ms": float(np.mean([r.queue_ms for r in results])),
+        "mean_tpot_ms": float(np.mean(tpots)),
+        "mean_e2e_ms": float(np.mean([r.e2e_ms for r in results])),
+        "compiled_step_programs": sum(
+            p.engine.compiled_programs()
+            for p in {id(p.engine): p for p in deployment.pairs
+                      if p.engine is not None}.values()),
+    }
+    if spec.workload.trace is not None:
+        from ..fleet.workload import serve_results_rows, slo_report
+        summary["slo"] = slo_report(serve_results_rows(results))
+    if hasattr(server, "pair_summaries"):
+        summary["pairs"] = server.pair_summaries()
+    # one-pair backcompat: the flat link keys the pre-topology launcher
+    # emitted, read off the single pair's transport
+    if len(deployment.pairs) == 1:
+        tr = deployment.pairs[0].transport
+        if tr is not None:
+            summary["transport"] = tr.describe()
+            summary["mode_policy"] = deployment.pairs[0].mode_policy
+            summary["link_bytes_sent"] = tr.bytes_sent
+            summary["link_messages"] = tr.messages_sent
+            summary["link_recent_rtt_ms"] = round(tr.recent_rtt_ms, 3)
+    return results, summary
 
 
 def main(argv=None) -> int:
@@ -126,6 +211,9 @@ def main(argv=None) -> int:
     ap.add_argument("--sync-every", type=int, default=8,
                     help="decode iterations between host stat syncs")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--full-width", action="store_true",
+                    help="serve every node's model at its published widths "
+                         "instead of the reduced CPU variant")
     ap.add_argument("--json", action="store_true")
     args = ap.parse_args(argv)
     if args.link_rtt_ms is not None and args.server == "wave":
@@ -137,84 +225,19 @@ def main(argv=None) -> int:
                          "transport; pass --link-rtt-ms (0 = in-process)")
 
     spec = spec_from_args(args)
+    enable_compile_cache()
     deployment = build_deployment(spec)
-    wl = spec.workload
-
-    if spec.serving.server == "wave":
-        pair0 = deployment.pairs[0]
-        cfg = deployment.server_config()
-        # the wave baseline reads mode_policy off its ServerConfig (it has
-        # no pair objects); forward the single pair's declared mode
-        cfg.mode_policy = pair0.mode_policy
-        server = WaveSpecDecodeServer(pair0.engine, pair0.policy, cfg)
-    else:
-        server = deployment.build_server()
-
-    fleet_reqs = None
-    if wl.trace is not None:
-        # fleet trace: class-aware arrivals with per-class SLOs attached
-        # to every request — the SAME stream build_simulation replays
-        from ..fleet.workload import fleet_serve_requests, generate_requests
-        fleet_reqs = generate_requests(wl.trace)
-        for req in fleet_serve_requests(fleet_reqs, deployment.vocab,
-                                        seed=spec.seed):
-            server.submit(req)
-    else:
-        rng = np.random.default_rng(spec.seed)
-        arrival = 0.0
-        for i in range(wl.num_requests):
-            plen = int(rng.integers(wl.prompt_lo, wl.prompt_hi))
-            if wl.rate_per_s > 0:
-                arrival += float(rng.exponential(1.0 / wl.rate_per_s))
-            server.submit(ServeRequest(
-                i, rng.integers(0, deployment.vocab, plen).astype(np.int32),
-                wl.max_new, arrival_s=arrival))
     try:
-        results = server.run()
+        results, summary = serve(spec, deployment, args.topology or "")
     finally:
-        # process-backed pairs hold worker subprocesses; their cached wave
-        # stats survive shutdown, so summaries below still read correctly
         deployment.shutdown()
-
-    accs = [r.acceptance_rate for r in results]
-    tpots = [r.tpot_ms for r in results]
-    summary = {
-        "server": spec.serving.server,
-        "topology": args.topology or "one-pair(flags)",
-        "pairs_deployed": len(deployment.pairs),
-        "requests": len(results),
-        "mean_acceptance": float(np.mean(accs)),
-        "mean_ttft_ms": float(np.mean([r.ttft_ms for r in results])),
-        "mean_queue_ms": float(np.mean([r.queue_ms for r in results])),
-        "mean_tpot_ms": float(np.mean(tpots)),
-        "mean_e2e_ms": float(np.mean([r.e2e_ms for r in results])),
-        "compiled_step_programs": sum(
-            p.engine.compiled_programs()
-            for p in {id(p.engine): p for p in deployment.pairs
-                      if p.engine is not None}.values()),
-    }
     if not args.topology:
         summary["policy"] = args.policy
-    if fleet_reqs is not None:
-        from ..fleet.workload import serve_results_rows, slo_report
-        summary["slo"] = slo_report(serve_results_rows(results))
-    if hasattr(server, "pair_summaries"):
-        summary["pairs"] = server.pair_summaries()
-    # one-pair backcompat: the flat link keys the pre-topology launcher
-    # emitted, read off the single pair's transport
-    if len(deployment.pairs) == 1:
-        tr = deployment.pairs[0].transport
-        if tr is not None:
-            summary["transport"] = tr.describe()
-            summary["mode_policy"] = deployment.pairs[0].mode_policy
-            summary["link_bytes_sent"] = tr.bytes_sent
-            summary["link_messages"] = tr.messages_sent
-            summary["link_recent_rtt_ms"] = round(tr.recent_rtt_ms, 3)
     if args.json:
         print(json.dumps(summary, indent=1))
     else:
         per_pair = ""
-        if len(deployment.pairs) > 1 and "pairs" in summary:
+        if summary["pairs_deployed"] > 1 and "pairs" in summary:
             per_pair = "  " + "  ".join(
                 (f"[{pid}: γ={d['mean_gamma']:.2f} "
                  f"fused={d['fused_fraction']:.2f} n={d['requests']}]")

@@ -93,6 +93,13 @@ class Model:
         raise ValueError(cfg.arch_type)
 
     def init_params(self, key: jax.Array) -> dict:
+        """Seeded random parameters, drawn by one jitted program per
+        config: each weight's float32 draw fuses into its cast, so at
+        published widths no float32 copy of a layer stack is ever held
+        beside the model."""
+        return _init_params(self.cfg, key)
+
+    def _draw_params(self, key: jax.Array) -> dict:
         cfg, dt = self.cfg, self.dtype
         keys = jax.random.split(key, 8)
         params: dict[str, Any] = {
@@ -610,6 +617,11 @@ class Model:
                                     uniform_pos=True)
         return self.verify_step(params, tokens, cache, pos0, window,
                                 seq_lens=prompt_lens)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _init_params(cfg: ModelConfig, key: jax.Array) -> dict:
+    return Model(cfg)._draw_params(key)
 
 
 def build_model(cfg: ModelConfig) -> Model:

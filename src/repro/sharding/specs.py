@@ -44,17 +44,11 @@ def _path_str(path) -> str:
 
 
 def abstract_mesh(axis_sizes: tuple[int, ...], axis_names: tuple[str, ...]):
-    """Version-compatible :class:`jax.sharding.AbstractMesh` constructor.
-
-    jax ≤ 0.4.x takes one ``((name, size), ...)`` shape tuple; newer
-    releases take ``(axis_sizes, axis_names)``. Spec/fit logic only needs
-    axis names and sizes, so tests and the dry-run build meshes through this
-    helper instead of pinning a jax version."""
+    """:class:`jax.sharding.AbstractMesh` over named axes: spec/fit logic
+    only needs axis names and sizes, so tests and the dry-run build meshes
+    through this helper without devices."""
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
+    return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
 
 
 def _axis_size_of(mesh: Mesh, axes) -> int:

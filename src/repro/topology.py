@@ -61,8 +61,9 @@ class NodeSpec:
     """One device in the deployment.
 
     ``model`` names a registered real-model config
-    (:func:`repro.configs.get_config`, reduced for host runs) unless the
-    factory is handed an override via ``model_configs``. ``device`` is a
+    (:func:`repro.configs.get_config`; see :func:`resolve_node_configs`
+    for reduced vs published widths) unless the factory is handed an
+    override via ``model_configs``. ``device`` is a
     placement hint for the real path; ``address``/``port`` place
     process-backed worker hosts (:mod:`repro.distributed.host`);
     ``hw``/``sim_model``/``tp`` feed the DSD-Sim hardware model and
@@ -128,6 +129,7 @@ class ServingSpec:
     rtt_ms: float = 0.0          # colocated pairs' virtual RTT charge
     router: str = "least-loaded"  # repro.serving.PAIR_ROUTERS key
     server: str = "continuous"   # continuous | wave (wave: 1 colocated pair)
+    paged_kv: bool = False       # paged block-pool KV (ServerConfig.paged_kv)
 
 
 @dataclass
@@ -155,12 +157,17 @@ class WorkloadSpec:
 
 @dataclass
 class ClusterSpec:
-    """The whole deployment: nodes + pairs + serving knobs + workload."""
+    """The whole deployment: nodes + pairs + serving knobs + workload.
+
+    ``full_width`` resolves every node's registered model at its published
+    widths; the default serves each model's ``.reduced()`` variant (2
+    layers, float32), which is what the CPU tests and benches run."""
     nodes: list[NodeSpec] = field(default_factory=list)
     pairs: list[PairSpec] = field(default_factory=list)
     serving: ServingSpec = field(default_factory=ServingSpec)
     workload: WorkloadSpec = field(default_factory=WorkloadSpec)
     seed: int = 0
+    full_width: bool = False
 
     # -- validation ----------------------------------------------------------
 
@@ -270,6 +277,9 @@ class ClusterSpec:
                 f"available: {sorted(PAIR_ROUTERS)}")
         if s.server not in ("continuous", "wave"):
             raise TopologyError(f"unknown serving.server {s.server!r}")
+        if s.paged_kv and s.server != "continuous":
+            raise TopologyError("serving.paged_kv needs the continuous "
+                                "server")
         if s.server == "wave" and (len(self.pairs) != 1
                                    or self.pairs[0].link is not None):
             raise TopologyError("serving.server='wave' is the single-pair "
@@ -330,7 +340,8 @@ class ClusterSpec:
             except WorkloadError as e:
                 raise TopologyError(f"workload.trace: {e}") from e
         return cls(nodes=nodes, pairs=pairs, serving=serving,
-                   workload=workload, seed=int(d.get("seed", 0)))
+                   workload=workload, seed=int(d.get("seed", 0)),
+                   full_width=bool(d.get("full_width", False)))
 
     @classmethod
     def from_json(cls, text: str) -> "ClusterSpec":
@@ -386,6 +397,51 @@ def one_pair_spec(target: str = "qwen3-14b", draft: str = "qwen2.5-3b",
 # real-path factory
 # --------------------------------------------------------------------------
 
+def resolve_node_configs(spec: ClusterSpec,
+                         model_configs: Optional[dict] = None
+                         ) -> tuple[dict, int]:
+    """Node id → :class:`~repro.configs.base.ModelConfig`, and the shared
+    vocabulary size. The one resolution rule of the deployment factory and
+    the worker hosts (:mod:`repro.distributed.host`):
+
+    - ``model_configs`` (name → config) overrides a node's ``model``, for
+      tests and benches with hand-built tiny configs;
+    - otherwise :func:`repro.configs.get_config`, at published widths when
+      ``spec.full_width`` and as its ``.reduced()`` variant otherwise;
+    - vocabularies are unified to the minimum across nodes (one tokenizer,
+      the legacy launcher rule)."""
+    from .configs import get_config
+    model_configs = model_configs or {}
+
+    def resolve(node: NodeSpec):
+        if node.model in model_configs:
+            return model_configs[node.model]
+        cfg = get_config(node.model)
+        return cfg if spec.full_width else cfg.reduced()
+
+    raw = {n.id: resolve(n) for n in spec.nodes}
+    vocab = min(c.vocab for c in raw.values())
+    configs = {nid: (c if c.vocab == vocab
+                     else dataclasses.replace(c, vocab=vocab))
+               for nid, c in raw.items()}
+    return configs, vocab
+
+
+def node_key(spec: ClusterSpec, node_id: str, key=None):
+    """The PRNG key node ``node_id``'s parameters are drawn from:
+    ``kd, kt = split(key or PRNGKey(spec.seed))`` and the i-th node of a
+    role (in ``spec.nodes`` order) folds in ``i`` (i > 0). Shared by
+    :func:`build_deployment` and the worker hosts, so a process-backed
+    node rebuilds exactly the parameters an in-process one would hold."""
+    import jax
+    base = jax.random.PRNGKey(spec.seed) if key is None else key
+    kd, kt = jax.random.split(base)
+    node = spec.node(node_id)
+    i = [n.id for n in spec.nodes if n.role == node.role].index(node_id)
+    k = kd if node.role == "draft" else kt
+    return jax.random.fold_in(k, i) if i > 0 else k
+
+
 @dataclass
 class Deployment:
     """The real execution path built from a spec: one
@@ -407,7 +463,7 @@ class Deployment:
                             length_aware=s.length_aware, pad_to=s.pad_to,
                             max_prompt_len=s.max_prompt_len,
                             max_new_cap=s.max_new_cap, eos_id=s.eos_id,
-                            sync_every=s.sync_every)
+                            sync_every=s.sync_every, paged_kv=s.paged_kv)
 
     def build_server(self):
         """A ready :class:`~repro.serving.SpecDecodeServer` over the
@@ -428,32 +484,31 @@ class Deployment:
 def build_deployment(spec: ClusterSpec, *,
                      model_configs: Optional[dict] = None,
                      node_params: Optional[dict] = None,
-                     key=None, sleep_links: bool = True,
-                     reduced: bool = True) -> Deployment:
+                     key=None, sleep_links: bool = True) -> Deployment:
     """Instantiate the real path from a validated spec.
 
-    - each node's ``model`` resolves through ``model_configs`` (name →
-      :class:`~repro.configs.base.ModelConfig`, for tests/benches with
-      hand-built tiny configs) or :func:`repro.configs.get_config`
-      (``.reduced()`` unless ``reduced=False``); vocabularies are unified
-      to the minimum across nodes (one tokenizer — exactly the legacy
-      launcher rule);
+    - node models resolve through :func:`resolve_node_configs` (overrides,
+      reduced or published widths per ``spec.full_width``, one unified
+      vocabulary);
     - parameters are built ONCE per node (``node_params`` overrides by
-      node id) and shared by every pair that references the node: the
-      PRNG scheme (``kd, kt = split(key)``; first draft/target node uses
-      ``kd``/``kt`` directly) reproduces the legacy
-      ``SpecDecodeEngine(..., key=key)`` initialization bit-for-bit for
-      a one-pair spec;
+      node id) and shared by every pair that references the node, from
+      :func:`node_key` — which reproduces the legacy
+      ``SpecDecodeEngine(..., key=key)`` initialization bit-for-bit for a
+      one-pair spec;
     - each pair gets its own engine (cached per (draft, target) node
       pair), its own transport from its :class:`LinkSpec`
       (:func:`repro.distributed.make_transport`; ``sleep_links=False``
       routes emulated delays to the virtual clock for fast tests), and
       its own window-policy instance — per-pair stabilizer isolation is
-      structural, not an accident of pair keys.
+      structural, not an accident of pair keys;
+    - process-backed pairs (``PairSpec.process``) are spawned by
+      :func:`repro.distributed.host.spawn_pair`, pair i of them on chips
+      ``2i`` and ``2i + 1`` of a TPU host. A deployment whose pairs are all
+      process-backed never touches JAX here, so this process does not
+      claim the chip its workers need.
     """
     import jax
 
-    from .configs import get_config
     from .core.engine import SpecDecodeEngine
     from .core.window import make_window_policy
     from .distributed import make_transport
@@ -463,18 +518,7 @@ def build_deployment(spec: ClusterSpec, *,
     model_configs = model_configs or {}
     node_params = node_params or {}
     s = spec.serving
-
-    def resolve(node: NodeSpec):
-        if node.model in model_configs:
-            return model_configs[node.model]
-        cfg = get_config(node.model)
-        return cfg.reduced() if reduced else cfg
-
-    raw = {n.id: resolve(n) for n in spec.nodes}
-    vocab = min(c.vocab for c in raw.values())
-    configs = {nid: (c if c.vocab == vocab
-                     else dataclasses.replace(c, vocab=vocab))
-               for nid, c in raw.items()}
+    configs, vocab = resolve_node_configs(spec, model_configs)
 
     process_pairs = [p for p in spec.pairs if p.process]
     if process_pairs and key is not None:
@@ -482,30 +526,21 @@ def build_deployment(spec: ClusterSpec, *,
             "process-backed pairs rebuild parameters from spec.seed inside "
             "the worker hosts; an explicit PRNG key cannot cross the process "
             "boundary — drop key= or set process=False")
+    if process_pairs:
+        from .distributed.host import check_worker_chips
+        check_worker_chips(2 * len(process_pairs))
     # nodes referenced by at least one in-process pair need local params;
     # process-only nodes are rebuilt inside their hosts from spec.seed
-    # (the role-index sweep below still walks EVERY node so indices match
-    # what the hosts derive).
     local_nodes = {nid for p in spec.pairs if not p.process
                    for nid in (p.draft, p.target)}
-
-    base = jax.random.PRNGKey(spec.seed) if key is None else key
-    kd, kt = jax.random.split(base)
-    role_index = {"draft": 0, "target": 0}
     params: dict[str, Any] = {}
     for n in spec.nodes:
-        i = role_index[n.role]
-        role_index[n.role] += 1
         if n.id in node_params:
             params[n.id] = node_params[n.id]
-            continue
-        if n.id not in local_nodes:
-            continue
-        from .models.model import build_model
-        k = kd if n.role == "draft" else kt
-        if i > 0:
-            k = jax.random.fold_in(k, i)
-        params[n.id] = build_model(configs[n.id]).init_params(k)
+        elif n.id in local_nodes:
+            from .models.model import build_model
+            params[n.id] = build_model(configs[n.id]).init_params(
+                node_key(spec, n.id, key))
 
     engines: dict[tuple[str, str], SpecDecodeEngine] = {}
     pairs = []
@@ -516,7 +551,8 @@ def build_deployment(spec: ClusterSpec, *,
                 spec, p, model_configs=model_configs,
                 node_params={nid: node_params[nid]
                              for nid in (p.draft, p.target)
-                             if nid in node_params})
+                             if nid in node_params},
+                first_chip=2 * process_pairs.index(p))
             w = p.window
             policy = make_window_policy(w.kind, gamma=w.gamma, hi=w.hi,
                                         lo=w.lo, gmax=w.gmax)

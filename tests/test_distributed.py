@@ -472,3 +472,45 @@ def test_process_pair_spec_validation():
         mutate(spec)
         with pytest.raises(TopologyError, match=msg):
             spec.validate()
+
+
+@pytest.mark.parametrize("holds,chips,msg", [
+    (True, 4, "already holds the TPU"),
+    (False, 1, "this host has 1"),
+], ids=["caller-holds-tpu", "too-few-chips"])
+def test_spawn_pair_refuses_at_once_on_a_tpu_host(monkeypatch, holds, chips,
+                                                  msg):
+    """One process per chip: on a TPU host, spawn_pair refuses before any
+    worker starts when this process already holds the TPU or the host has
+    fewer chips than worker processes — it never waits out the handshake.
+    The elastic pool and build_deployment go through the same check."""
+    import subprocess
+
+    import repro.distributed.host as host
+    from repro.fleet.elastic import ElasticPairPool
+    from repro.topology import (ClusterSpec, NodeSpec, PairSpec, ServingSpec,
+                                TopologyError, WindowSpec, WorkloadSpec,
+                                build_deployment)
+    monkeypatch.setattr(host, "holds_tpu", lambda: holds)
+    monkeypatch.setattr(host, "host_tpu_chips", lambda: chips)
+
+    def no_spawn(*a, **k):
+        raise AssertionError("a worker process was started")
+
+    monkeypatch.setattr(subprocess, "Popen", no_spawn)
+    spec = ClusterSpec(
+        nodes=[NodeSpec(id="e", role="draft", model="d"),
+               NodeSpec(id="c", role="target", model="t")],
+        pairs=[PairSpec(id="p", draft="e", target="c",
+                        window=WindowSpec(kind="static", gamma=3),
+                        mode_policy="distributed", process=True)],
+        serving=ServingSpec(max_batch=1, server="continuous",
+                            temperature=0.0),
+        workload=WorkloadSpec(num_requests=1, max_new=4))
+    cfgs = {"d": DRAFT, "t": TARGETS["dense"]}
+    with pytest.raises(TopologyError, match="one process per chip"):
+        host.spawn_pair(spec, spec.pairs[0], model_configs=cfgs)
+    with pytest.raises(TopologyError, match=msg):
+        build_deployment(spec, model_configs=cfgs)
+    with pytest.raises(TopologyError, match="one process per chip"):
+        ElasticPairPool(spec, model_configs=cfgs).scale_up()

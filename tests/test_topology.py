@@ -376,3 +376,119 @@ def test_build_deployment_rejects_explicit_key_with_process_pairs():
     with pytest.raises(TopologyError, match="seed"):
         build_deployment(spec, model_configs=TINY,
                          key=jax.random.PRNGKey(0))
+
+
+# ------------------------------------------------- published widths / chips
+
+def test_full_width_round_trips_and_resolves_published_widths():
+    """ClusterSpec.full_width survives JSON (older files without it load
+    as reduced) and switches every registered node to its published
+    widths, with the vocabulary unified across nodes."""
+    from repro.topology import resolve_node_configs
+    spec = one_pair_spec(target="qwen2.5-3b", draft="mamba2-130m")
+    assert spec.full_width is False
+    spec.full_width = True
+    again = ClusterSpec.from_json(spec.to_json())
+    assert again == spec and again.full_width is True
+    d = spec.to_dict()
+    del d["full_width"]
+    assert ClusterSpec.from_dict(d).full_width is False
+
+    full, vocab = resolve_node_configs(spec)
+    t, dr = full["cloud0"], full["edge0"]
+    assert (t.n_layers, t.d_model, t.n_heads, t.n_kv_heads, t.head_dim,
+            t.d_ff, t.dtype) == (36, 2048, 16, 2, 128, 11008, "bfloat16")
+    assert (dr.n_layers, dr.d_model, dr.ssm_state) == (24, 768, 128)
+    assert vocab == t.vocab == dr.vocab == 50280
+    small, _ = resolve_node_configs(dataclasses.replace(spec,
+                                                        full_width=False))
+    assert small["cloud0"].n_layers == 2 and small["cloud0"].vocab == 512
+
+
+def test_worker_host_resolves_like_build_deployment(tmp_path):
+    """A worker host rebuilds exactly the configs and parameters
+    build_deployment holds for the same node (one resolution rule, one
+    PRNG scheme), so a process-backed pair decodes the in-process pair's
+    model."""
+    import argparse
+
+    from repro.distributed.host import _HostContext
+    spec = ClusterSpec(
+        nodes=[NodeSpec("e0", "draft", "mamba2-130m"),
+               NodeSpec("e1", "draft", "qwen2.5-3b"),
+               NodeSpec("c0", "target", "qwen2.5-3b")],
+        pairs=[PairSpec("p0", "e1", "c0", window=WindowSpec("static", 2),
+                        mode_policy="distributed")],
+        serving=ServingSpec(max_batch=1, gamma_max=2),
+        workload=WorkloadSpec(num_requests=1, max_new=4), seed=5)
+    dep = build_deployment(spec)
+    eng = dep.pairs[0].engine
+
+    path = tmp_path / "topo.json"
+    proc = dataclasses.replace(spec, pairs=[dataclasses.replace(
+        spec.pairs[0], process=True)])
+    path.write_text(proc.to_json())
+    ctx = _HostContext(argparse.Namespace(
+        topology=str(path), pair="p0", role="target", model_config=[],
+        node_params=[]))
+    host_eng = ctx.build_engine()
+    assert host_eng.draft_cfg == eng.draft_cfg == dep.node_configs["e1"]
+    assert host_eng.target_cfg == eng.target_cfg == dep.node_configs["c0"]
+    for got, want in ((host_eng.draft_params, eng.draft_params),
+                      (host_eng.target_params, eng.target_params)):
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_process_only_deployment_builds_no_local_params(monkeypatch):
+    """Nodes that only process-backed pairs use are rebuilt inside their
+    worker hosts, so build_deployment draws no parameters for them — the
+    parent stays off the chip its workers need."""
+    import repro.distributed.host as host
+    import repro.topology as topology
+
+    def no_params(*a, **k):
+        raise AssertionError("drew parameters for a process-only node")
+
+    monkeypatch.setattr(topology, "node_key", no_params)
+    spawned = []
+    monkeypatch.setattr(host, "spawn_pair",
+                        lambda spec, pair, **kw: spawned.append(
+                            (pair.id, kw["first_chip"])) or object())
+    spec = ClusterSpec(
+        nodes=[NodeSpec("e0", "draft", "topo-d"),
+               NodeSpec("e1", "draft", "topo-d"),
+               NodeSpec("c0", "target", "topo-t"),
+               NodeSpec("c1", "target", "topo-t")],
+        pairs=[PairSpec(pid, e, c, window=WindowSpec("static", 2),
+                        mode_policy="distributed", process=True)
+               for pid, e, c in (("p0", "e0", "c0"), ("p1", "e1", "c1"))],
+        serving=ServingSpec(max_batch=1, gamma_max=2),
+        workload=WorkloadSpec(num_requests=1, max_new=4))
+    dep = build_deployment(spec, model_configs=TINY)
+    assert spawned == [("p0", 0), ("p1", 2)]
+    assert all(p.engine is None for p in dep.pairs)
+
+
+def test_compile_cache_dir_comes_from_the_environment_or_the_repo(
+        monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins when set, and nothing overrides it;
+    otherwise the entry points use the fixed <repo>/.jax_cache."""
+    from pathlib import Path
+
+    from repro.launch import compile_cache as cc
+    repo = Path(__file__).resolve().parents[1]
+    assert cc.cache_dir({}) == str(repo / ".jax_cache")
+    assert cc.cache_dir({cc.ENV_VAR: "/data/cache"}) == "/data/cache"
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(cc.ENV_VAR, "/data/cache")
+        assert cc.enable_compile_cache() == "/data/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv(cc.ENV_VAR)
+        assert cc.enable_compile_cache() == str(repo / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(
+            repo / ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
