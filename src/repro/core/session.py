@@ -47,6 +47,7 @@ from ..models.kvcache import BlockAllocator, logical_blocks, reset_slot
 # committed tokens — the same amortization DSD-Sim's ``fused_chunk``
 # charges (one shared constant so sim and real paths cannot drift)
 from ..sim.network import DEFAULT_FUSED_CHUNK as FUSED_FLUSH_TOKENS
+from ..tracing import span
 from .engine import DEFAULT_GAMMA_MAX, GenerationStats
 from .specdec import SpecDecodeState
 from .window import FeatureSnapshot
@@ -424,7 +425,8 @@ class DecodeSession:
         return list(range(B))
 
     def admit(self, prompt: np.ndarray, max_new: int, request_id: int = 0,
-              slot: Optional[int] = None, block: bool = True) -> int:
+              slot: Optional[int] = None, block: bool = True,
+              arrival_s: Optional[float] = None) -> int:
         """Admit one request into a free slot of a LIVE session.
 
         Runs the jitted prefill-insert: the prompt (right-padded to
@@ -432,47 +434,61 @@ class DecodeSession:
         anchor token and lifecycle entries are scattered into the chosen
         slot. The request's first token exists when this returns (with
         ``block=True``) — per-request TTFT is measured from its own
-        prefill-insert, not from any wave's."""
+        prefill-insert, not from any wave's. ``arrival_s`` (the request's
+        due time on the serve loop's clock) labels the ``dsd.admit``
+        span."""
         free = self.free
         if not free:
             raise RuntimeError("no free slot; retire a finished request first")
         j = free[0] if slot is None else slot
         assert self._slots[j] is None, f"slot {j} is occupied"
-        self._ensure_state()
         prompt = np.asarray(prompt, np.int32).reshape(-1)
+        meta = {} if arrival_s is None else {"arrival_s": float(arrival_s)}
+        with span("admit", request_id=request_id, prompt_len=prompt.size,
+                  **meta):
+            budget = self._insert(prompt, max_new, j, block)
+        self._slots[j] = SlotRecord(request_id=request_id, max_new=budget,
+                                    admit_it=self.iterations)
+        return j
+
+    def _insert(self, prompt: np.ndarray, max_new: int, j: int,
+                block: bool) -> int:
+        """The prefill-insert of :meth:`admit` into slot ``j``; returns
+        the request's clamped budget."""
+        self._ensure_state()
         P = self.max_prompt_len
         assert 1 <= prompt.size <= P, (prompt.size, P)
         padded = np.zeros((1, P), np.int32)
         padded[0, :prompt.size] = prompt
         budget = min(int(max_new), self.max_new_cap)
-        self._key, kk = jax.random.split(self._key)
-        args = (self.engine.draft_params, self.engine.target_params,
-                self._state, self._out_buf, self._cursor, self._max_new,
-                self._done, jnp.asarray(padded),
-                jnp.asarray([prompt.size], jnp.int32),
-                jnp.asarray(j, jnp.int32), jnp.asarray(budget, jnp.int32),
-                kk)
-        if self.paged:
-            blocks = self._reserve_blocks(prompt.size, budget)
-            insert = self.engine._insert_step_paged(
-                self.capacity, self.slots_len, P,
-                blocks["draft"].shape[0], blocks["target"].shape[0])
-            (self._state, self._out_buf, self._cursor, self._max_new,
-             self._done) = insert(*args, jnp.asarray(blocks["draft"]),
-                                  jnp.asarray(blocks["target"]))
-            self._slot_blocks[j] = {
-                s: [int(i) for i in ids if i >= 0]
-                for s, ids in blocks.items() if ids.size}
-        else:
-            insert = self.engine._insert_step(self.capacity, self.slots_len,
-                                              P)
-            (self._state, self._out_buf, self._cursor, self._max_new,
-             self._done) = insert(*args)
+        with span("admit.dispatch"):
+            self._key, kk = jax.random.split(self._key)
+            args = (self.engine.draft_params, self.engine.target_params,
+                    self._state, self._out_buf, self._cursor, self._max_new,
+                    self._done, jnp.asarray(padded),
+                    jnp.asarray([prompt.size], jnp.int32),
+                    jnp.asarray(j, jnp.int32), jnp.asarray(budget, jnp.int32),
+                    kk)
+            if self.paged:
+                blocks = self._reserve_blocks(prompt.size, budget)
+                insert = self.engine._insert_step_paged(
+                    self.capacity, self.slots_len, P,
+                    blocks["draft"].shape[0], blocks["target"].shape[0])
+                (self._state, self._out_buf, self._cursor, self._max_new,
+                 self._done) = insert(*args, jnp.asarray(blocks["draft"]),
+                                      jnp.asarray(blocks["target"]))
+                self._slot_blocks[j] = {
+                    s: [int(i) for i in ids if i >= 0]
+                    for s, ids in blocks.items() if ids.size}
+            else:
+                insert = self.engine._insert_step(self.capacity,
+                                                  self.slots_len, P)
+                (self._state, self._out_buf, self._cursor, self._max_new,
+                 self._done) = insert(*args)
         if block:
-            jax.block_until_ready(self._cursor)
-        self._slots[j] = SlotRecord(request_id=request_id, max_new=budget,
-                                    admit_it=self.iterations)
-        return j
+            with span("admit.wait"):
+                jax.block_until_ready(self._cursor)
+        return budget
 
     def _reserve_blocks(self, prompt_len: int, budget: int
                         ) -> dict[str, np.ndarray]:
@@ -508,7 +524,8 @@ class DecodeSession:
         window, so nothing is accepted and the target's own next token is
         committed (a pure cloud-side autoregressive step). γ = 0 is data,
         not shape: fused/distributed switching never recompiles."""
-        dec = policy.decide(self.pair_key, self._features(q_depth))
+        with span("decide"):
+            dec = policy.decide(self.pair_key, self._features(q_depth))
         if self.mode_policy == "fused":
             fused = True
         elif self.mode_policy == "distributed":
@@ -559,10 +576,18 @@ class DecodeSession:
         ``mode_policy="pipeline"`` overlaps consecutive distributed rounds
         over the full-duplex transport (:meth:`_run_chunk_pipeline`); the
         half-duplex exchange stays the default."""
-        if self.transport is not None:
+        active = sum(1 for r in self._slots if r is not None and not r.done)
+        with span("chunk", active=active):
+            if self.transport is None:
+                return self._run_chunk_colocated(policy, max_iters, q_depth)
             if self.mode_policy == "pipeline":
                 return self._run_chunk_pipeline(policy, max_iters, q_depth)
             return self._run_chunk_transport(policy, max_iters, q_depth)
+
+    def _run_chunk_colocated(self, policy, max_iters: Optional[int],
+                             q_depth: float) -> int:
+        """Up to ``sync_every`` rounds of the engine's fused colocated
+        step, dispatched back to back, then one host sync."""
         n = self.sync_every
         if max_iters is not None:
             n = min(n, max_iters - self.iterations)
@@ -575,30 +600,35 @@ class DecodeSession:
         chunk_t0 = time.perf_counter()
         chunk_gammas: list[int] = []
         for r in range(n):
-            gamma, _fused = self._decide(policy, q_depth)
+            gamma, fused = self._decide(policy, q_depth)
             chunk_gammas.append(gamma)
-            self._key, ks = jax.random.split(self._key)
-            if tree:
-                # γ = 0 (fused decision) masks every non-anchor node:
-                # only the target's own next token commits, same as the
-                # linear step's fused round
-                (self._state, self._out_buf, self._cursor, self._nacc,
-                 self._nn, self._done) = step(
-                    eng.draft_params, eng.target_params, self._state, ks,
-                    jnp.asarray(gamma, jnp.int32),
-                    jnp.asarray(self._branches_eff, jnp.int32),
-                    jnp.asarray(r, jnp.int32),
-                    self._out_buf, self._cursor, self._nacc, self._nn,
-                    self._max_new, self._done,
-                    jnp.asarray(self.eos_id, jnp.int32))
-            else:
-                (self._state, self._out_buf, self._cursor, self._nacc,
-                 self._nn, self._done) = step(
-                    eng.draft_params, eng.target_params, self._state, ks,
-                    jnp.asarray(gamma, jnp.int32), jnp.asarray(r, jnp.int32),
-                    self._out_buf, self._cursor, self._nacc, self._nn,
-                    self._max_new, self._done,
-                    jnp.asarray(self.eos_id, jnp.int32))
+            with span("round", gamma=gamma, fused=fused):
+                self._key, ks = jax.random.split(self._key)
+                with span("step.dispatch"):
+                    if tree:
+                        # γ = 0 (fused decision) masks every non-anchor node:
+                        # only the target's own next token commits, same as
+                        # the linear step's fused round
+                        (self._state, self._out_buf, self._cursor, self._nacc,
+                         self._nn, self._done) = step(
+                            eng.draft_params, eng.target_params,
+                            self._state, ks,
+                            jnp.asarray(gamma, jnp.int32),
+                            jnp.asarray(self._branches_eff, jnp.int32),
+                            jnp.asarray(r, jnp.int32),
+                            self._out_buf, self._cursor, self._nacc, self._nn,
+                            self._max_new, self._done,
+                            jnp.asarray(self.eos_id, jnp.int32))
+                    else:
+                        (self._state, self._out_buf, self._cursor, self._nacc,
+                         self._nn, self._done) = step(
+                            eng.draft_params, eng.target_params,
+                            self._state, ks,
+                            jnp.asarray(gamma, jnp.int32),
+                            jnp.asarray(r, jnp.int32),
+                            self._out_buf, self._cursor, self._nacc, self._nn,
+                            self._max_new, self._done,
+                            jnp.asarray(self.eos_id, jnp.int32))
             self.iterations += 1
         self._sync_and_attribute(n, chunk_gammas, chunk_t0,
                                  non_target_ms=0.0,
@@ -623,13 +653,14 @@ class DecodeSession:
                         jnp.float32)
                 q_probs = self._q_zero
             args.append(q_probs)
-        (tcache, new_pos, new_last, self._out_buf, self._cursor,
-         self._nacc, self._nn, self._done, num_new_dev, nacc_dev,
-         next_raw) = tw.verify_commit(self.gamma_max)(
-            *args, self._out_buf, self._cursor, self._nacc, self._nn,
-            self._max_new, self._done,
-            jnp.asarray(row_idx, jnp.int32), jnp.asarray(self.eos_id,
-                                                         jnp.int32))
+        with span("verify", gamma=gamma):
+            (tcache, new_pos, new_last, self._out_buf, self._cursor,
+             self._nacc, self._nn, self._done, num_new_dev, nacc_dev,
+             next_raw) = tw.verify_commit(self.gamma_max)(
+                *args, self._out_buf, self._cursor, self._nacc, self._nn,
+                self._max_new, self._done,
+                jnp.asarray(row_idx, jnp.int32), jnp.asarray(self.eos_id,
+                                                             jnp.int32))
         return tcache, new_pos, new_last, num_new_dev, nacc_dev, next_raw
 
     def _verify_commit_tree_round(self, tw, tree_np: np.ndarray, gamma: int,
@@ -639,15 +670,17 @@ class DecodeSession:
         path KV relocation on the target cache. Returns the winning path
         too — the draft side relocates its propose cache with it."""
         state = self._state
-        (tcache, new_pos, new_last, self._out_buf, self._cursor,
-         self._nacc, self._nn, self._done, num_new_dev, nacc_dev,
-         next_raw, path_dev) = tw.verify_commit_tree(
-            self.gamma_max, self.max_branches)(
-            tw.params, state.target_cache, jnp.asarray(tree_np), state.pos,
-            jnp.asarray(gamma, jnp.int32), jnp.asarray(branches, jnp.int32),
-            self._out_buf, self._cursor, self._nacc, self._nn,
-            self._max_new, self._done, jnp.asarray(row_idx, jnp.int32),
-            jnp.asarray(self.eos_id, jnp.int32))
+        with span("verify", gamma=gamma):
+            (tcache, new_pos, new_last, self._out_buf, self._cursor,
+             self._nacc, self._nn, self._done, num_new_dev, nacc_dev,
+             next_raw, path_dev) = tw.verify_commit_tree(
+                self.gamma_max, self.max_branches)(
+                tw.params, state.target_cache, jnp.asarray(tree_np),
+                state.pos, jnp.asarray(gamma, jnp.int32),
+                jnp.asarray(branches, jnp.int32),
+                self._out_buf, self._cursor, self._nacc, self._nn,
+                self._max_new, self._done, jnp.asarray(row_idx, jnp.int32),
+                jnp.asarray(self.eos_id, jnp.int32))
         return (tcache, new_pos, new_last, num_new_dev, nacc_dev, next_raw,
                 path_dev)
 
@@ -662,20 +695,125 @@ class DecodeSession:
         cost). Returns the unhidden link delay imposed by stream flushes."""
         state = self._state
         window_np = np.zeros((self.capacity, self.gamma_max + 1), np.int32)
-        window_np[:, 0] = np.asarray(state.last_token)
+        with span("verdict.sync"):
+            window_np[:, 0] = np.asarray(state.last_token)
         (tcache, new_pos, new_last, num_new_dev, _nacc, _next) = \
             self._verify_commit_round(tw, window_np, 0, row_idx, None,
                                       sampled, key)
-        dcache = dw.ingest()(dw.params, state.draft_cache, state.last_token,
-                             state.pos, num_new_dev)
+        with span("ingest"):
+            dcache = dw.ingest()(dw.params, state.draft_cache,
+                                 state.last_token, state.pos, num_new_dev)
         link_ms = 0.0
-        self._fused_pending += int(np.asarray(num_new_dev).sum())
+        with span("verdict.sync"):
+            self._fused_pending += int(np.asarray(num_new_dev).sum())
         while self._fused_pending >= FUSED_FLUSH_TOKENS:
             link_ms += self.transport.control_roundtrip()
             self._fused_pending -= FUSED_FLUSH_TOKENS
         self._state = SpecDecodeState(draft_cache=dcache, target_cache=tcache,
                                       last_token=new_last, pos=new_pos)
         return link_ms
+
+    def _tree_round(self, dw, tw, gamma: int, n_active: int, row_idx: int
+                    ) -> tuple[float, float, np.ndarray]:
+        """One tree round over the transport: the grid window crosses the
+        wire with its parent table (node-count-priced payload), the verdict
+        carries the winning path back so the draft can relocate its
+        propose cache identically to the target's commit. Returns (link
+        delay ms, draft ms, done flags on the host)."""
+        from ..distributed.wire import VerdictMsg, WindowMsg
+        tr = self.transport
+        state = self._state
+        b = self._branches_eff
+        t_draft = time.perf_counter()
+        with span("propose", gamma=gamma):
+            toks, dcache_prop = dw.propose_tree(
+                self.gamma_max, self.max_branches)(
+                dw.params, state.draft_cache, state.last_token, state.pos)
+            toks_np = np.asarray(toks)
+        draft_ms = (time.perf_counter() - t_draft) * 1e3
+        rid = self._round_seq
+        self._round_seq += 1
+        msg = WindowMsg(tokens=toks_np, gamma=gamma, n_active=n_active,
+                        round_id=rid, n_nodes=toks_np.shape[1], branches=b,
+                        parent=self._tree_spec.parent_np)
+        link_ms = tr.send_window(msg)
+        (tcache, new_pos, new_last, num_new_dev, nacc_dev,
+         next_raw, path_dev) = self._verify_commit_tree_round(
+            tw, msg.tokens, gamma, b, row_idx)
+        with span("verdict.sync"):
+            done_host = np.asarray(self._done)
+            verdict = VerdictMsg(
+                n_accepted=np.asarray(nacc_dev),
+                num_new=np.asarray(num_new_dev),
+                next_token=np.asarray(next_raw),
+                last_token=np.asarray(new_last),
+                done=done_host, gamma=gamma, n_active=n_active,
+                round_id=rid, path=np.asarray(path_dev))
+        link_ms += tr.send_verdict(verdict)
+        with span("ingest"):
+            dcache = dw.ingest_tree(self.gamma_max, self.max_branches)(
+                dcache_prop, state.pos, jnp.asarray(verdict.path),
+                jnp.asarray(verdict.n_accepted))
+        self._state = SpecDecodeState(draft_cache=dcache, target_cache=tcache,
+                                      last_token=new_last, pos=new_pos)
+        return link_ms, draft_ms, done_host
+
+    def _linear_round(self, dw, tw, gamma: int, n_active: int, row_idx: int,
+                      kd, kv) -> tuple[float, float, np.ndarray]:
+        """One half-duplex linear round over the transport: propose, ship
+        the window, verify/commit, ship the verdict, bring the draft's
+        cache to the committed prefix. Returns (link delay ms, draft ms,
+        done flags on the host)."""
+        from ..distributed.wire import VerdictMsg, WindowMsg
+        G = self.gamma_max
+        tr = self.transport
+        sampled = self.engine.temperature > 0.0
+        state = self._state
+        # timing the propose dispatch through the host materialize
+        # isolates the draft's serial scan — excluded from the TPOT
+        # feature like the sim excludes its draft time
+        t_draft = time.perf_counter()
+        with span("propose", gamma=gamma):
+            toks, q_probs, dcache_prop = dw.propose(G)(
+                dw.params, state.draft_cache, state.last_token, state.pos, kd)
+            toks_np = np.asarray(toks)
+        draft_ms = (time.perf_counter() - t_draft) * 1e3
+        rid = self._round_seq
+        self._round_seq += 1
+        msg = WindowMsg(tokens=toks_np, gamma=gamma, n_active=n_active,
+                        q_probs=q_probs if sampled else None, round_id=rid)
+        link_ms = tr.send_window(msg)
+        window_np = np.concatenate(
+            [np.asarray(state.last_token)[:, None], msg.tokens], axis=1)
+        (tcache, new_pos, new_last, num_new_dev, nacc_dev,
+         next_raw) = self._verify_commit_round(
+            tw, window_np, gamma, row_idx, q_probs if sampled else None,
+            sampled, kv)
+        with span("verdict.sync"):
+            done_host = np.asarray(self._done)
+            verdict = VerdictMsg(
+                n_accepted=np.asarray(nacc_dev),
+                num_new=np.asarray(num_new_dev),
+                next_token=np.asarray(next_raw),
+                last_token=np.asarray(new_last),
+                done=done_host, gamma=gamma, n_active=n_active,
+                round_id=rid)
+        link_ms += tr.send_verdict(verdict)
+        if dw.attention:
+            dcache = dcache_prop   # pos_map masks the stale tail
+        else:
+            # recurrent draft: re-advance the pre-window checkpoint over
+            # the committed prefix. The correction token never enters the
+            # advance (it is committed at position pos+num_new−1 and only
+            # processed by the NEXT round), so the [anchor, proposals]
+            # window is the advance input.
+            with span("advance"):
+                dcache = dw.advance(G)(dw.params, state.draft_cache,
+                                       jnp.asarray(window_np), state.pos,
+                                       num_new_dev)
+        self._state = SpecDecodeState(draft_cache=dcache, target_cache=tcache,
+                                      last_token=new_last, pos=new_pos)
+        return link_ms, draft_ms, done_host
 
     def _run_chunk_transport(self, policy, max_iters: Optional[int],
                              q_depth: float) -> int:
@@ -693,7 +831,6 @@ class DecodeSession:
         tokens must exist as bytes to cross a wire — so this path pays a
         full RTT of dead time per committed window; hiding it is exactly
         what the pipelined mode is for."""
-        from ..distributed.wire import VerdictMsg, WindowMsg
         n = self.sync_every
         if max_iters is not None:
             n = min(n, max_iters - self.iterations)
@@ -701,7 +838,6 @@ class DecodeSession:
             return 0
         eng = self.engine
         dw, tw = eng.split_workers()
-        G = self.gamma_max
         B = self.capacity
         tr = self.transport
         sampled = eng.temperature > 0.0
@@ -716,97 +852,22 @@ class DecodeSession:
                 break
             gamma, fused = self._decide(policy, q_depth)
             n_active = int(B - done_host.sum())
-            self._key, ks = jax.random.split(self._key)
-            kd, kv = jax.random.split(ks)
-            state = self._state
-            if fused:
-                link_ms += self._fused_round(dw, tw, r, sampled, kv)
-                done_host = np.asarray(self._done)
-            elif self.max_branches:
-                # tree round: the grid window crosses the wire with its
-                # parent table (node-count-priced payload), the verdict
-                # carries the winning path back so the draft can relocate
-                # its propose cache identically to the target's commit
-                b = self._branches_eff
-                t_draft = time.perf_counter()
-                toks, dcache_prop = dw.propose_tree(
-                    self.gamma_max, self.max_branches)(
-                    dw.params, state.draft_cache, state.last_token,
-                    state.pos)
-                toks_np = np.asarray(toks)
-                draft_ms += (time.perf_counter() - t_draft) * 1e3
-                rid = self._round_seq
-                self._round_seq += 1
-                msg = WindowMsg(tokens=toks_np, gamma=gamma,
-                                n_active=n_active, round_id=rid,
-                                n_nodes=toks_np.shape[1], branches=b,
-                                parent=self._tree_spec.parent_np)
-                link_ms += tr.send_window(msg)
-                (tcache, new_pos, new_last, num_new_dev, nacc_dev,
-                 next_raw, path_dev) = self._verify_commit_tree_round(
-                    tw, msg.tokens, gamma, b, r)
-                done_host = np.asarray(self._done)
-                verdict = VerdictMsg(
-                    n_accepted=np.asarray(nacc_dev),
-                    num_new=np.asarray(num_new_dev),
-                    next_token=np.asarray(next_raw),
-                    last_token=np.asarray(new_last),
-                    done=done_host, gamma=gamma, n_active=n_active,
-                    round_id=rid, path=np.asarray(path_dev))
-                link_ms += tr.send_verdict(verdict)
-                dcache = dw.ingest_tree(self.gamma_max, self.max_branches)(
-                    dcache_prop, state.pos, jnp.asarray(verdict.path),
-                    jnp.asarray(verdict.n_accepted))
-                self._state = SpecDecodeState(
-                    draft_cache=dcache, target_cache=tcache,
-                    last_token=new_last, pos=new_pos)
-            else:
-                # timing the propose dispatch through the host materialize
-                # isolates the draft's serial scan — excluded from the
-                # TPOT feature like the sim excludes its draft time
-                t_draft = time.perf_counter()
-                toks, q_probs, dcache_prop = dw.propose(G)(
-                    dw.params, state.draft_cache, state.last_token,
-                    state.pos, kd)
-                toks_np = np.asarray(toks)
-                draft_ms += (time.perf_counter() - t_draft) * 1e3
-                rid = self._round_seq
-                self._round_seq += 1
-                msg = WindowMsg(tokens=toks_np, gamma=gamma,
-                                n_active=n_active,
-                                q_probs=q_probs if sampled else None,
-                                round_id=rid)
-                link_ms += tr.send_window(msg)
-                window_np = np.concatenate(
-                    [np.asarray(state.last_token)[:, None], msg.tokens],
-                    axis=1)
-                (tcache, new_pos, new_last, num_new_dev, nacc_dev,
-                 next_raw) = self._verify_commit_round(
-                    tw, window_np, gamma, r,
-                    q_probs if sampled else None, sampled, kv)
-                done_host = np.asarray(self._done)
-                verdict = VerdictMsg(
-                    n_accepted=np.asarray(nacc_dev),
-                    num_new=np.asarray(num_new_dev),
-                    next_token=np.asarray(next_raw),
-                    last_token=np.asarray(new_last),
-                    done=done_host, gamma=gamma, n_active=n_active,
-                    round_id=rid)
-                link_ms += tr.send_verdict(verdict)
-                if dw.attention:
-                    dcache = dcache_prop   # pos_map masks the stale tail
+            with span("round", gamma=gamma, fused=fused):
+                self._key, ks = jax.random.split(self._key)
+                kd, kv = jax.random.split(ks)
+                if fused:
+                    link_ms += self._fused_round(dw, tw, r, sampled, kv)
+                    with span("verdict.sync"):
+                        done_host = np.asarray(self._done)
                 else:
-                    # recurrent draft: re-advance the pre-window checkpoint
-                    # over the committed prefix. The correction token never
-                    # enters the advance (it is committed at position
-                    # pos+num_new−1 and only processed by the NEXT round),
-                    # so the [anchor, proposals] window is the advance input.
-                    dcache = dw.advance(G)(dw.params, state.draft_cache,
-                                           jnp.asarray(window_np),
-                                           state.pos, num_new_dev)
-                self._state = SpecDecodeState(
-                    draft_cache=dcache, target_cache=tcache,
-                    last_token=new_last, pos=new_pos)
+                    if self.max_branches:
+                        out = self._tree_round(dw, tw, gamma, n_active, r)
+                    else:
+                        out = self._linear_round(dw, tw, gamma, n_active, r,
+                                                 kd, kv)
+                    link_ms += out[0]
+                    draft_ms += out[1]
+                    done_host = out[2]
             chunk_gammas.append(gamma)
             self.iterations += 1
             it_run += 1
@@ -856,11 +917,13 @@ class DecodeSession:
         sampled = eng.temperature > 0.0
         max_new_host = np.asarray(self._max_new)
         t0 = time.perf_counter()
-        self._key, kd = jax.random.split(self._key)
-        toks, q_probs, dcache_prop = dw.propose(G)(
-            dw.params, state.draft_cache, state.last_token, state.pos, kd)
-        toks_np = np.asarray(toks)
-        last_host = np.asarray(state.last_token)
+        with span("propose", gamma=gamma):
+            self._key, kd = jax.random.split(self._key)
+            toks, q_probs, dcache_prop = dw.propose(G)(
+                dw.params, state.draft_cache, state.last_token, state.pos,
+                kd)
+            toks_np = np.asarray(toks)
+            last_host = np.asarray(state.last_token)
         window_np = np.concatenate([last_host[:, None], toks_np], axis=1)
 
         # -- optimistic post-round prediction (slot_stop_mask mirror) ------
@@ -890,9 +953,10 @@ class DecodeSession:
             # recurrent draft: optimistic re-advance of the pre-window
             # checkpoint over the assumed-committed prefix — the same
             # jitted program a miss's rollback runs (zero recompiles)
-            opt_cache = dw.advance(G)(dw.params, state.draft_cache,
-                                      jnp.asarray(window_np), state.pos,
-                                      num_eff_dev)
+            with span("advance"):
+                opt_cache = dw.advance(G)(dw.params, state.draft_cache,
+                                          jnp.asarray(window_np), state.pos,
+                                          num_eff_dev)
         draft_ms = (time.perf_counter() - t0) * 1e3
 
         rid = self._round_seq
@@ -964,96 +1028,108 @@ class DecodeSession:
                 gamma, fused = (carry if carry is not None
                                 else self._decide(policy, q_depth))
                 carry = None
+            else:
+                gamma, fused = pending["gamma"], False
+            with span("round", gamma=gamma, fused=fused):
                 if fused:
                     self._key, kf = jax.random.split(self._key)
                     link_ms += self._fused_round(dw, tw, it_run, sampled, kf)
-                    done_host = np.asarray(self._done)
-                    # the fused round advanced the device cursors: refresh
-                    # the host mirror or later optimistic budget/EOS
-                    # predictions in this chunk would run understated and
-                    # force spurious rollbacks near the budget edge
-                    cursor_host = np.asarray(self._cursor).copy()
+                    with span("verdict.sync"):
+                        done_host = np.asarray(self._done)
+                        # the fused round advanced the device cursors:
+                        # refresh the host mirror or later optimistic
+                        # budget/EOS predictions in this chunk would run
+                        # understated and force spurious rollbacks near the
+                        # budget edge
+                        cursor_host = np.asarray(self._cursor).copy()
                     chunk_gammas.append(0)
                     self.iterations += 1
                     it_run += 1
                     continue
-                pending = self._make_window(dw, self._state, gamma,
-                                            done_host, cursor_host,
-                                            speculative=False)
-                draft_ms += pending["draft_ms"]
+                if pending is None:
+                    pending = self._make_window(dw, self._state, gamma,
+                                                done_host, cursor_host,
+                                                speculative=False)
+                    draft_ms += pending["draft_ms"]
 
-            # -- target: receive + verify the in-flight window ------------
-            wmsg, waited = tr.recv_window()
-            link_ms += waited
-            window_np = np.concatenate(
-                [np.asarray(self._state.last_token)[:, None], wmsg.tokens],
-                axis=1)
-            self._key, kv = jax.random.split(self._key)
-            (tcache, new_pos, new_last, num_new_dev, nacc_dev, next_raw) = \
-                self._verify_commit_round(tw, window_np, wmsg.gamma, it_run,
-                                          pending["q_probs"], sampled, kv)
-            verdict = VerdictMsg(
-                n_accepted=np.asarray(nacc_dev),
-                num_new=np.asarray(num_new_dev),
-                next_token=np.asarray(next_raw),
-                last_token=np.asarray(new_last),
-                done=np.asarray(self._done), gamma=wmsg.gamma,
-                n_active=wmsg.n_active, round_id=wmsg.round_id)
-            tr.post_verdict(verdict)
+                # -- target: receive + verify the in-flight window --------
+                wmsg, waited = tr.recv_window()
+                link_ms += waited
+                with span("verdict.sync"):
+                    last_host = np.asarray(self._state.last_token)
+                window_np = np.concatenate([last_host[:, None], wmsg.tokens],
+                                           axis=1)
+                self._key, kv = jax.random.split(self._key)
+                (tcache, new_pos, new_last, num_new_dev, nacc_dev,
+                 next_raw) = self._verify_commit_round(
+                    tw, window_np, wmsg.gamma, it_run, pending["q_probs"],
+                    sampled, kv)
+                with span("verdict.sync"):
+                    verdict = VerdictMsg(
+                        n_accepted=np.asarray(nacc_dev),
+                        num_new=np.asarray(num_new_dev),
+                        next_token=np.asarray(next_raw),
+                        last_token=np.asarray(new_last),
+                        done=np.asarray(self._done), gamma=wmsg.gamma,
+                        n_active=wmsg.n_active, round_id=wmsg.round_id)
+                tr.post_verdict(verdict)
 
-            # -- draft: speculate window k+1 while the verdict flies -------
-            spec = None
-            if it_run + 1 < n and not pending["opt_done"].all():
-                gamma2, fused2 = self._decide(policy, q_depth)
-                if fused2:
-                    carry = (gamma2, fused2)   # fused runs unpipelined
+                # -- draft: speculate window k+1 while the verdict flies ---
+                spec = None
+                if it_run + 1 < n and not pending["opt_done"].all():
+                    gamma2, fused2 = self._decide(policy, q_depth)
+                    if fused2:
+                        carry = (gamma2, fused2)   # fused runs unpipelined
+                    else:
+                        spec = self._make_window(
+                            dw, pending["opt_state"], gamma2,
+                            pending["opt_done"],
+                            cursor_host + pending["opt_num_new"],
+                            speculative=True)
+                        draft_ms += spec["draft_ms"]
+
+                # -- resolve the verdict ----------------------------------
+                _vmsg, waited = tr.recv_verdict()
+                link_ms += waited
+                hit = (np.array_equal(verdict.num_new,
+                                      pending["opt_num_new"])
+                       and np.array_equal(verdict.done, pending["opt_done"])
+                       and np.array_equal(verdict.last_token,
+                                          pending["opt_last"]))
+                if hit:
+                    self.pipeline_hits += 1
+                    self._pipe_recent.append(1.0)
+                    dcache = pending["opt_state"].draft_cache
                 else:
-                    spec = self._make_window(
-                        dw, pending["opt_state"], gamma2,
-                        pending["opt_done"],
-                        cursor_host + pending["opt_num_new"],
-                        speculative=True)
-                    draft_ms += spec["draft_ms"]
-
-            # -- resolve the verdict --------------------------------------
-            _vmsg, waited = tr.recv_verdict()
-            link_ms += waited
-            hit = (np.array_equal(verdict.num_new, pending["opt_num_new"])
-                   and np.array_equal(verdict.done, pending["opt_done"])
-                   and np.array_equal(verdict.last_token,
-                                      pending["opt_last"]))
-            if hit:
-                self.pipeline_hits += 1
-                self._pipe_recent.append(1.0)
-                dcache = pending["opt_state"].draft_cache
-            else:
-                self.pipeline_misses += 1
-                self._pipe_recent.append(0.0)
-                if dw.attention:
-                    dcache = pending["prop_cache"]
+                    self.pipeline_misses += 1
+                    self._pipe_recent.append(0.0)
+                    if dw.attention:
+                        dcache = pending["prop_cache"]
+                    else:
+                        with span("advance"):
+                            dcache = dw.advance(G)(
+                                dw.params, pending["ckpt_cache"],
+                                pending["window_dev"], pending["base_pos"],
+                                num_new_dev)
+                self._state = SpecDecodeState(
+                    draft_cache=dcache, target_cache=tcache,
+                    last_token=new_last, pos=new_pos)
+                done_host = verdict.done
+                cursor_host = cursor_host + verdict.num_new
+                chunk_gammas.append(wmsg.gamma)
+                self.iterations += 1
+                it_run += 1
+                if hit and spec is not None:
+                    pending = spec            # the pipelined window is live
                 else:
-                    dcache = dw.advance(G)(dw.params, pending["ckpt_cache"],
-                                           pending["window_dev"],
-                                           pending["base_pos"], num_new_dev)
-            self._state = SpecDecodeState(
-                draft_cache=dcache, target_cache=tcache,
-                last_token=new_last, pos=new_pos)
-            done_host = verdict.done
-            cursor_host = cursor_host + verdict.num_new
-            chunk_gammas.append(wmsg.gamma)
-            self.iterations += 1
-            it_run += 1
-            if hit and spec is not None:
-                pending = spec            # the pipelined window is live
-            else:
-                if spec is not None:      # late verdict invalidates it
-                    tr.discard_window()
-                    # the re-draft reuses the invalidated window's γ
-                    # decision (it was made pre-verdict — that is what
-                    # pipelining means), keeping policy calls and
-                    # gamma_seq 1:1 with committed rounds
-                    carry = (spec["gamma"], False)
-                pending = None
+                    if spec is not None:      # late verdict invalidates it
+                        tr.discard_window()
+                        # the re-draft reuses the invalidated window's γ
+                        # decision (it was made pre-verdict — that is what
+                        # pipelining means), keeping policy calls and
+                        # gamma_seq 1:1 with committed rounds
+                        carry = (spec["gamma"], False)
+                    pending = None
         if carry is not None:
             # a decision was made for a round that never ran (the batch
             # drained or the chunk ended first): unwind its bookkeeping
@@ -1097,10 +1173,11 @@ class DecodeSession:
         distributed round plus the per-token amortized stream flush for
         fused commits — the same charges the transport path and DSD-Sim
         make, so ``virtual_ms`` stays comparable across paths."""
-        cur = np.asarray(self._cursor)
-        done = np.asarray(self._done)
-        nacc = np.asarray(self._nacc[:n])
-        nn = np.asarray(self._nn[:n])
+        with span("chunk.sync"):
+            cur = np.asarray(self._cursor)
+            done = np.asarray(self._done)
+            nacc = np.asarray(self._nacc[:n])
+            nn = np.asarray(self._nn[:n])
         # wall time is measured AFTER the blocking host transfers above:
         # the colocated loop dispatches its jitted steps asynchronously,
         # so the chunk's device compute only completes here
@@ -1137,6 +1214,11 @@ class DecodeSession:
             rec.done = bool(done[j])
 
         active_iters = int((nn > 0).sum())
+        # the chunk's counters, as a marker in the trace
+        with span("chunk.commit", tokens=chunk_tokens,
+                  slot_rounds=active_iters,
+                  fused=sum(1 for g in chunk_gammas if g == 0)):
+            pass
         mean_tok = chunk_tokens / max(1, active_iters)
         compute_ms = max(0.0, chunk_wall * 1e3 - non_target_ms)
         self._tpot_recent.append((compute_ms / n) / max(1.0, mean_tok))

@@ -45,6 +45,7 @@ from collections import deque
 
 from ..sim.network import (LinkSpec, RttTracker, expected_rtt_ms,
                            sample_one_way_ms)
+from ..tracing import span
 from .wire import TransportProtocolError, VerdictMsg, WindowMsg
 
 CONTROL_PAYLOAD_BYTES = 64   # fused-mode chunk flush / control messages
@@ -139,14 +140,16 @@ class Transport:
         return self._post(FWD, msg, msg.payload_bytes, msg.round_id)
 
     def recv_window(self) -> tuple:
-        return self._recv(FWD)
+        with span("link.window"):
+            return self._recv(FWD)
 
     def post_verdict(self, msg: VerdictMsg) -> float:
         """Target → draft, non-blocking. Returns the sampled delay (ms)."""
         return self._post(BWD, msg, msg.payload_bytes, msg.round_id)
 
     def recv_verdict(self) -> tuple:
-        return self._recv(BWD)
+        with span("link.verdict"):
+            return self._recv(BWD)
 
     def discard_window(self):
         """Drop the oldest in-flight draft→target message without waiting:
@@ -171,20 +174,23 @@ class Transport:
         """Post + immediately wait out the delivery (half-duplex path).
         Returns the imposed one-way delay (ms)."""
         self.post_window(msg)
-        return self._recv(FWD)[1]
+        with span("link.window"):
+            return self._recv(FWD)[1]
 
     def send_verdict(self, msg: VerdictMsg) -> float:
         """Target → draft, blocking. Returns the imposed delay (ms)."""
         self.post_verdict(msg)
-        return self._recv(BWD)[1]
+        with span("link.verdict"):
+            return self._recv(BWD)[1]
 
     def control_roundtrip(self,
                           payload_bytes: int = CONTROL_PAYLOAD_BYTES) -> float:
         """One small out+back exchange (fused-mode token-stream flush)."""
-        out = self._post(FWD, None, payload_bytes)
-        _, w1 = self._recv(FWD)
-        back = self._post(BWD, None, payload_bytes)
-        _, w2 = self._recv(BWD)
+        with span("link.control"):
+            out = self._post(FWD, None, payload_bytes)
+            _, w1 = self._recv(FWD)
+            back = self._post(BWD, None, payload_bytes)
+            _, w2 = self._recv(BWD)
         self._rtt.record_rtt(out + back)
         return w1 + w2
 

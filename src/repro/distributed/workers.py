@@ -75,12 +75,12 @@ class DraftWorker:
             return cache[keyt]
         decode = lambda p, t, c, pos: self.model.decode_step(p, t, c, pos)
 
-        def fn(params, dcache, last_token, pos, key):
+        def propose(params, dcache, last_token, pos, key):
             prop = draft_propose(decode, params, dcache, last_token, pos,
                                  gamma_max, key, self.temperature)
             return prop.tokens, prop.q_probs, prop.cache
 
-        cache[keyt] = jax.jit(fn)
+        cache[keyt] = jax.jit(propose)
         return cache[keyt]
 
     def propose_tree(self, d_max: int, b_max: int):
@@ -99,11 +99,11 @@ class DraftWorker:
             "tree speculation needs an attention-family draft"
         spec = TreeSpec(d_max, b_max)
 
-        def fn(params, dcache, last_token, pos):
+        def propose_tree(params, dcache, last_token, pos):
             return tree_propose(self.model, params, dcache, last_token,
                                 pos, spec)
 
-        cache[keyt] = jax.jit(fn)
+        cache[keyt] = jax.jit(propose_tree)
         return cache[keyt]
 
     def ingest_tree(self, d_max: int, b_max: int):
@@ -121,11 +121,11 @@ class DraftWorker:
             "tree speculation needs an attention-family draft"
         n_entries = 1 + d_max * b_max
 
-        def fn(dcache, pos, path, n_accepted):
+        def ingest_tree(dcache, pos, path, n_accepted):
             return tree_commit_cache(dcache, pos, path, n_accepted,
                                      n_entries)
 
-        cache[keyt] = jax.jit(fn)
+        cache[keyt] = jax.jit(ingest_tree)
         return cache[keyt]
 
     def ingest(self):
@@ -141,11 +141,11 @@ class DraftWorker:
         if keyt in cache:
             return cache[keyt]
 
-        def fn(params, dcache, token, pos, num_new):
+        def ingest(params, dcache, token, pos, num_new):
             _, cnew = self.model.decode_step(params, token, dcache, pos)
             return _tree_where(num_new > 0, cnew, dcache)
 
-        cache[keyt] = jax.jit(fn)
+        cache[keyt] = jax.jit(ingest)
         return cache[keyt]
 
     def advance(self, gamma_max: int):
@@ -161,11 +161,11 @@ class DraftWorker:
             return cache[keyt]
         decode = lambda p, t, c, pos: self.model.decode_step(p, t, c, pos)
 
-        def fn(params, dcache, adv_tokens, pos, num_new):
+        def advance(params, dcache, adv_tokens, pos, num_new):
             return _scan_cache_advance(decode, params, dcache, adv_tokens,
                                        pos, num_new)
 
-        cache[keyt] = jax.jit(fn)
+        cache[keyt] = jax.jit(advance)
         return cache[keyt]
 
 
@@ -206,9 +206,9 @@ class TargetWorker:
             return cache[keyt]
         greedy = self.temperature <= 0.0
 
-        def core(params, tcache, window, pos, active_gamma, key, q_probs,
-                 out_buf, cursor, nacc_buf, nn_buf, max_new, done, row_idx,
-                 eos_id):
+        def commit(params, tcache, window, pos, active_gamma, key, q_probs,
+                   out_buf, cursor, nacc_buf, nn_buf, max_new, done, row_idx,
+                   eos_id):
             draft_tokens = window[:, 1:]
             p_logits, tcache_spec = self.model.verify_step(
                 params, window, tcache, pos)
@@ -249,15 +249,22 @@ class TargetWorker:
                     nacc_buf, nn_buf, stop.done, stop.num_new,
                     stop.n_accepted, res.next_token)
 
+        # the jitted function's name is the program's name in the trace
         if greedy:
-            def fn(params, tcache, window, pos, active_gamma, key, out_buf,
-                   cursor, nacc_buf, nn_buf, max_new, done, row_idx, eos_id):
-                return core(params, tcache, window, pos, active_gamma, key,
-                            None, out_buf, cursor, nacc_buf, nn_buf,
-                            max_new, done, row_idx, eos_id)
+            def verify_commit(params, tcache, window, pos, active_gamma, key,
+                              out_buf, cursor, nacc_buf, nn_buf, max_new,
+                              done, row_idx, eos_id):
+                return commit(params, tcache, window, pos, active_gamma, key,
+                              None, out_buf, cursor, nacc_buf, nn_buf,
+                              max_new, done, row_idx, eos_id)
         else:
-            fn = core
-        cache[keyt] = jax.jit(fn)
+            def verify_commit(params, tcache, window, pos, active_gamma, key,
+                              q_probs, out_buf, cursor, nacc_buf, nn_buf,
+                              max_new, done, row_idx, eos_id):
+                return commit(params, tcache, window, pos, active_gamma, key,
+                              q_probs, out_buf, cursor, nacc_buf, nn_buf,
+                              max_new, done, row_idx, eos_id)
+        cache[keyt] = jax.jit(verify_commit)
         return cache[keyt]
 
     def verify_commit_tree(self, d_max: int, b_max: int):
@@ -290,9 +297,10 @@ class TargetWorker:
         spec = TreeSpec(d_max, b_max)
         T = spec.n_entries
 
-        def fn(params, tcache, tree_tokens, pos, active_gamma, branches,
-               out_buf, cursor, nacc_buf, nn_buf, max_new, done, row_idx,
-               eos_id):
+        def verify_commit_tree(params, tcache, tree_tokens, pos,
+                               active_gamma, branches, out_buf, cursor,
+                               nacc_buf, nn_buf, max_new, done, row_idx,
+                               eos_id):
             p_logits, tcache_spec = self.model.verify_step(
                 params, tree_tokens, tcache, pos,
                 slot_off=jnp.arange(T, dtype=jnp.int32),
@@ -316,5 +324,5 @@ class TargetWorker:
                     nacc_buf, nn_buf, stop.done, stop.num_new,
                     stop.n_accepted, res.next_token, res.path)
 
-        cache[keyt] = jax.jit(fn)
+        cache[keyt] = jax.jit(verify_commit_tree)
         return cache[keyt]

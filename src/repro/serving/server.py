@@ -53,6 +53,7 @@ import numpy as np
 from ..core.engine import SpecDecodeEngine
 from ..core.session import DecodeSession
 from ..core.window import StaticWindowPolicy, WindowPolicy
+from ..tracing import span
 
 
 @dataclass
@@ -334,19 +335,48 @@ class SpecDecodeServer:
         for pair, sess in zip(self.pairs, sessions):
             pair.session = sess     # routers read live acceptance/occupancy
         self._served = [0] * len(self.pairs)
-        clock = _ArrivalClock()
         # request_id -> (request, admit_start_s, first_token_s, pair_idx)
         in_flight: dict[int, tuple[ServeRequest, float, float, int]] = {}
+        clock = _ArrivalClock()
+        # arrival_s is measured from the clock's start, so the span opens
+        # with it: a request is due at the span's start + its arrival_s
+        with span("serve", requests=len(pending)):
+            while pending or any(s.occupied for s in sessions):
+                arrived = self._admit_arrived(sessions, pending, in_flight,
+                                              clock)
+                if not any(s.occupied for s in sessions):
+                    with span("serve.idle"):
+                        clock.wait_until(min(r.arrival_s for r in pending))
+                    continue
+                # q_depth: requests that have ARRIVED and wait for a slot —
+                # future arrivals must not leak into policy features
+                q_depth = len(arrived) / max(1, 4 * self.cfg.max_batch)
+                for idx, sess in enumerate(sessions):
+                    if not sess.occupied:
+                        continue
+                    sess.run_chunk(self.pairs[idx].policy, q_depth=q_depth)
+                    for j in sess.finished_slots():
+                        with span("retire",
+                                  request_id=sess.record(j).request_id):
+                            self._retire(idx, sess, j, in_flight, clock)
+        return self.results
 
-        while pending or any(s.occupied for s in sessions):
-            now = clock.now()
-            arrived = [r for r in pending if r.arrival_s <= now]
-            if (arrived and all(p.draining for p in self.pairs)
-                    and not any(s.occupied for s in sessions)):
-                raise RuntimeError(
-                    "every pair is draining with requests still pending — "
-                    "undrain a pair to keep serving")
-            while arrived:
+    def _admit_arrived(self, sessions: list[DecodeSession],
+                       pending: list[ServeRequest], in_flight: dict,
+                       clock: _ArrivalClock) -> list[ServeRequest]:
+        """Route and admit arrived requests into free slots (head-of-line
+        request picks its pair via the router, LAB/FIFO co-admission fills
+        that pair's remaining slots). Returns the arrived requests still
+        waiting."""
+        now = clock.now()
+        arrived = [r for r in pending if r.arrival_s <= now]
+        if (arrived and all(p.draining for p in self.pairs)
+                and not any(s.occupied for s in sessions)):
+            raise RuntimeError(
+                "every pair is draining with requests still pending — "
+                "undrain a pair to keep serving")
+        while arrived:
+            with span("serve.route", arrived=len(arrived)):
                 # a draining pair advertises zero free slots: routers skip
                 # it, in-flight sequences keep decoding until retirement
                 frees = [0 if p.draining else len(s.free)
@@ -361,60 +391,53 @@ class SpecDecodeServer:
                 if routed is None:
                     continue    # head shed; retry with the next head
                 idx = routed
-                admitted_any = False
-                for r in self._select_admissions(arrived, frees[idx]):
-                    # block-aware admission: a paged session may have a free
-                    # slot but not enough free KV blocks for this request's
-                    # budget — skip it and let retirements free blocks
-                    # (can_admit == slot check for dense sessions)
-                    if not sessions[idx].can_admit(len(r.prompt),
-                                                   r.max_new_tokens):
-                        continue
-                    admit_start = clock.now()
-                    sessions[idx].admit(r.prompt, r.max_new_tokens,
-                                        request_id=r.request_id)
-                    in_flight[r.request_id] = (r, admit_start, clock.now(),
-                                               idx)
-                    pending.remove(r)
-                    arrived.remove(r)
-                    self._served[idx] += 1
-                    admitted_any = True
-                if not admitted_any:
-                    break  # no capacity progress — decode to free blocks
-            if not any(s.occupied for s in sessions):
-                clock.wait_until(min(r.arrival_s for r in pending))
-                continue
-            # q_depth: requests that have ARRIVED and wait for a slot —
-            # future arrivals must not leak into policy features
-            q_depth = len(arrived) / max(1, 4 * self.cfg.max_batch)
-            for idx, sess in enumerate(sessions):
-                if not sess.occupied:
+                chosen = self._select_admissions(arrived, frees[idx])
+            admitted_any = False
+            for r in chosen:
+                # block-aware admission: a paged session may have a free
+                # slot but not enough free KV blocks for this request's
+                # budget — skip it and let retirements free blocks
+                # (can_admit == slot check for dense sessions)
+                if not sessions[idx].can_admit(len(r.prompt),
+                                               r.max_new_tokens):
                     continue
-                sess.run_chunk(self.pairs[idx].policy, q_depth=q_depth)
-                for j in sess.finished_slots():
-                    tokens, rec = sess.retire(j)
-                    r, admit_s, first_tok_s, _ = in_flight.pop(rec.request_id)
-                    end_s = clock.now()
-                    n = len(tokens)
-                    bits = rec.bits
-                    ttft = (first_tok_s - r.arrival_s) * 1e3
-                    tpot = (end_s - first_tok_s) * 1e3 / max(1, n - 1)
-                    self._ttft_q[idx].push(ttft)
-                    self._tpot_q[idx].push(tpot)
-                    self.results.append(ServeResult(
-                        request_id=r.request_id,
-                        tokens=tokens,
-                        ttft_ms=ttft,
-                        tpot_ms=tpot,
-                        e2e_ms=(end_s - r.arrival_s) * 1e3,
-                        acceptance_rate=(sum(bits) / len(bits)) if bits
-                        else 0.0,
-                        queue_ms=(admit_s - r.arrival_s) * 1e3,
-                        pair_id=self.pairs[idx].pair_id,
-                        request_class=r.request_class,
-                        slo_ttft_ms=r.slo_ttft_ms,
-                        slo_tpot_ms=r.slo_tpot_ms))
-        return self.results
+                admit_start = clock.now()
+                sessions[idx].admit(r.prompt, r.max_new_tokens,
+                                    request_id=r.request_id,
+                                    arrival_s=r.arrival_s)
+                in_flight[r.request_id] = (r, admit_start, clock.now(), idx)
+                pending.remove(r)
+                arrived.remove(r)
+                self._served[idx] += 1
+                admitted_any = True
+            if not admitted_any:
+                break  # no capacity progress — decode to free blocks
+        return arrived
+
+    def _retire(self, idx: int, sess: DecodeSession, slot: int,
+                in_flight: dict, clock: _ArrivalClock) -> None:
+        """Retire a finished slot of pair ``idx`` and record its result."""
+        tokens, rec = sess.retire(slot)
+        r, admit_s, first_tok_s, _ = in_flight.pop(rec.request_id)
+        end_s = clock.now()
+        n = len(tokens)
+        bits = rec.bits
+        ttft = (first_tok_s - r.arrival_s) * 1e3
+        tpot = (end_s - first_tok_s) * 1e3 / max(1, n - 1)
+        self._ttft_q[idx].push(ttft)
+        self._tpot_q[idx].push(tpot)
+        self.results.append(ServeResult(
+            request_id=r.request_id,
+            tokens=tokens,
+            ttft_ms=ttft,
+            tpot_ms=tpot,
+            e2e_ms=(end_s - r.arrival_s) * 1e3,
+            acceptance_rate=(sum(bits) / len(bits)) if bits else 0.0,
+            queue_ms=(admit_s - r.arrival_s) * 1e3,
+            pair_id=self.pairs[idx].pair_id,
+            request_class=r.request_class,
+            slo_ttft_ms=r.slo_ttft_ms,
+            slo_tpot_ms=r.slo_tpot_ms))
 
     # -- SLO-aware admission -------------------------------------------------
 
