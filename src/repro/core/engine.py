@@ -44,7 +44,10 @@ free:
 - ``SpecDecodeState`` caches, the output token buffer, the write cursors
   and the stats buffers are DONATED to the jitted step
   (``donate_argnums``) so KV/SSM buffers update in place instead of copying
-  every iteration.
+  every iteration. The dense layer loop and the draft's γ scan carry the
+  stacked KV cache as loop state (models/model.py), so the donated stack is
+  the one buffer every draft token and the verify write into, and it
+  aliases the step's output.
 - Committed tokens accumulate into a preallocated on-device
   ``(B, max_new)`` buffer with per-sequence write cursors; per-iteration
   ``n_accepted``/``num_new`` land in device-side ring buffers. The host

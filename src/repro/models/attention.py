@@ -172,7 +172,8 @@ def attention_decode(x_new: jax.Array, p: dict, cfg: ModelConfig,
                      window: int = 0, uniform_pos: bool = False,
                      slot_off: Optional[jax.Array] = None,
                      pos_off: Optional[jax.Array] = None,
-                     win_mask: Optional[jax.Array] = None):
+                     win_mask: Optional[jax.Array] = None,
+                     layer: Optional[jax.Array] = None):
     """Decode/verify step: write the (B,T) window into the cache, attend over
     valid slots.
 
@@ -190,6 +191,10 @@ def attention_decode(x_new: jax.Array, p: dict, cfg: ModelConfig,
     ``slot_pos ≤ q_pos`` rule cannot separate them; the ancestor bitmap
     does. Slots outside the region keep the base rule (the committed
     prefix stays visible).
+
+    ``layer`` (int32 scalar): the caches are the whole (L, B, S, ...)
+    stack, written and attended at layer ``layer`` (see
+    :func:`repro.models.kvcache.update_layer_cache`).
     Returns (out, k_cache, v_cache, pos_map).
     """
     B, T, _ = x_new.shape
@@ -200,10 +205,12 @@ def attention_decode(x_new: jax.Array, p: dict, cfg: ModelConfig,
     k_new = apply_rope(k_new, abs_pos, cfg.rope_theta)
     k_cache, v_cache, pos_map = update_layer_cache(
         k_cache, v_cache, pos_map, k_new, v_new, pos, ring,
-        uniform_pos=uniform_pos, slot_off=slot_off, pos_off=pos_off)
-
-    out = _attend_cached(q, k_cache, v_cache, pos_map, abs_pos, window,
-                         p["wo"], x_new.dtype, win_mask=win_mask, pos=pos)
+        uniform_pos=uniform_pos, slot_off=slot_off, pos_off=pos_off,
+        layer=layer)
+    kc, vc, pm = (k_cache, v_cache, pos_map) if layer is None else (
+        c[layer] for c in (k_cache, v_cache, pos_map))
+    out = _attend_cached(q, kc, vc, pm, abs_pos, window, p["wo"],
+                         x_new.dtype, win_mask=win_mask, pos=pos)
     return out, k_cache, v_cache, pos_map
 
 

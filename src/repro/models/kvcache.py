@@ -81,15 +81,30 @@ def init_attn_cache(n_layers: int, batch: int, slots: int, n_kv: int,
         ring=ring)
 
 
+# lanes of a TPU vector register: the minor dim of every (8, 128) tile
+_LANES = 128
+
+
 def update_layer_cache(k_cache: jax.Array, v_cache: jax.Array,
                        pos_map: jax.Array, k_new: jax.Array,
                        v_new: jax.Array, pos: jax.Array, ring,
                        uniform_pos: bool = False,
                        slot_off: Optional[jax.Array] = None,
-                       pos_off: Optional[jax.Array] = None
+                       pos_off: Optional[jax.Array] = None,
+                       layer: Optional[jax.Array] = None
                        ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Write a (B, T, Hkv, hd) window into one layer's cache at per-sequence
     positions ``pos`` (B,). Returns updated (k, v, pos_map).
+
+    ``layer`` (int32 scalar, may be traced): the caches are the whole
+    (L, B, S, ...) stack a layer loop carries, and the window lands in
+    layer ``layer``, in place. A head_dim that fills whole 128-lane rows
+    keeps (Hkv, hd) minor in the TPU's layout, and the window's rows are
+    scattered into the stack. A narrower head_dim (qwen2.5-0.5b's 64) is
+    laid out with positions on the lanes, where a scatter would relay the
+    whole stack out and back around every call; there each slot of the
+    layer takes its window row if one lands on it, a select over the layer
+    that attention reads anyway.
 
     Non-ring writes past the cache edge (``pos + t >= S``) are DROPPED —
     the cache keeps its newest committed KV instead of silently overwriting
@@ -103,15 +118,16 @@ def update_layer_cache(k_cache: jax.Array, v_cache: jax.Array,
     position == ``pos + t``, the linear layout.
 
     ``uniform_pos=True`` asserts all sequences share one position (aligned
-    serving waves / chunked prefill): the write lowers to a
+    serving waves / prefill): the write lowers to a
     ``dynamic_update_slice``, which GSPMD partitions cleanly — the general
     per-sequence scatter forces an involuntary resharding/replication of the
     cache inside the decode loop (XLA spmd_partitioner limitation) and is
     kept only for ragged engine batches. Uniform positions make overflow
-    all-or-nothing, so the guard is a ``lax.cond`` skipping the whole
-    write."""
+    all-or-nothing: an overflowing window writes back what its slots
+    already hold."""
     B, T = k_new.shape[0], k_new.shape[1]
-    S = k_cache.shape[1]
+    S = pos_map.shape[-1]
+    lead = () if layer is None else (layer,)
     if uniform_pos:
         assert slot_off is None and pos_off is None
         p0 = pos[0]
@@ -120,18 +136,18 @@ def update_layer_cache(k_cache: jax.Array, v_cache: jax.Array,
         slot0 = jnp.where(ring, p0 % S, jnp.minimum(p0, S - T))
         abs_pos = (p0 + jnp.arange(T))[None, :].astype(jnp.int32) \
             + jnp.zeros((B, 1), jnp.int32)
-
-        def _write(ops):
-            kc, vc, pm = ops
-            kc = jax.lax.dynamic_update_slice(kc, k_new, (0, slot0, 0, 0))
-            vc = jax.lax.dynamic_update_slice(vc, v_new, (0, slot0, 0, 0))
-            pm = jax.lax.dynamic_update_slice(pm, abs_pos, (0, slot0))
-            return kc, vc, pm
-
         overflow = jnp.logical_and(jnp.logical_not(jnp.asarray(ring)),
                                    p0 + T > S)
-        return jax.lax.cond(overflow, lambda ops: ops, _write,
-                            (k_cache, v_cache, pos_map))
+
+        def write(c, new):
+            new = new.reshape((1,) * len(lead) + new.shape)
+            start = lead + (0, slot0) + (0,) * (new.ndim - len(lead) - 2)
+            old = jax.lax.dynamic_slice(c, start, new.shape)
+            return jax.lax.dynamic_update_slice(
+                c, jnp.where(overflow, old, new), start)
+
+        return (write(k_cache, k_new), write(v_cache, v_new),
+                write(pos_map, abs_pos))
     if slot_off is not None or pos_off is not None:
         # ``ring`` may arrive as a traced scalar (sessions canonicalize the
         # static flag into an array); the static check only fires when the
@@ -147,10 +163,31 @@ def update_layer_cache(k_cache: jax.Array, v_cache: jax.Array,
     write_pos = pos[:, None] + s_off[None, :]
     slot = jnp.where(ring, write_pos % S, write_pos)
 
+    if layer is not None and k_cache.shape[-1] % _LANES:
+        hit = slot[:, :, None] == jnp.arange(S)                   # (B,T,S)
+        # a window longer than a ring wraps onto its own slots: the last
+        # row written to a slot wins, as in a sequential scatter
+        t_idx = jnp.arange(T)[None, :, None]
+        hit &= t_idx == jnp.max(jnp.where(hit, t_idx, -1), 1, keepdims=True)
+
+        def select(c, new):
+            old = c[layer]
+            tail = (1,) * (old.ndim - 2)
+            mask = hit.reshape(hit.shape + tail)
+            # one window row at most is kept per slot: the sum is that row
+            rows = jnp.where(mask, jnp.expand_dims(new, 2),
+                             jnp.zeros((), new.dtype)).sum(1, new.dtype)
+            written = mask.any(1)
+            return jax.lax.dynamic_update_index_in_dim(
+                c, jnp.where(written, rows, old), layer, 0)
+
+        return (select(k_cache, k_new), select(v_cache, v_new),
+                select(pos_map, abs_pos))
     batch_idx = jnp.arange(B)[:, None].repeat(T, axis=1)      # (B, T)
-    k_cache = k_cache.at[batch_idx, slot].set(k_new, mode="drop")
-    v_cache = v_cache.at[batch_idx, slot].set(v_new, mode="drop")
-    pos_map = pos_map.at[batch_idx, slot].set(abs_pos, mode="drop")
+    idx = lead + (batch_idx, slot)
+    k_cache = k_cache.at[idx].set(k_new, mode="drop")
+    v_cache = v_cache.at[idx].set(v_new, mode="drop")
+    pos_map = pos_map.at[idx].set(abs_pos, mode="drop")
     return k_cache, v_cache, pos_map
 
 

@@ -20,7 +20,12 @@ API (uniform across families, everything jit/pjit-able):
     logits, cache = model.verify_step(params, window, cache, pos)  # T = γ+1
 
 Layers are stacked and scanned (``lax.scan``) so HLO size and compile time
-stay flat in depth — required for the 80-layer archs in the dry-run.
+stay flat in depth — required for the 80-layer archs in the dry-run. In
+the dense decode/verify loop the stacked KV cache is loop state, not a
+scanned input: layer ``l`` writes its window into row ``l`` of the carried
+stack and attends over that row, so XLA updates one buffer in place through
+the layer loop and through any loop around the step (the draft's γ scan),
+and a donated cache aliases the step's output.
 """
 
 from __future__ import annotations
@@ -412,18 +417,25 @@ class Model:
             return self._logits(params, h), new_cache
 
         if cfg.arch_type in ("dense", "vlm", "moe"):
-            def layer(h, inp):
-                lp, kc, vc, pm = inp
-                a, kc, vc, pm = attention_decode(
+            # the stacked cache is loop state: layer l writes and reads its
+            # row of the carried stack, which XLA updates in place (a
+            # scanned xs -> ys stack is a second buffer the caller's loop
+            # would copy back every step)
+            def layer(carry, inp):
+                h, k, v, pm = carry
+                lp, l = inp
+                a, k, v, pm = attention_decode(
                     rms_norm(h, lp["ln1"], cfg.norm_eps), lp["attn"], cfg,
-                    kc, vc, pm, pos, cache.ring, w, uniform_pos,
-                    slot_off=slot_off, pos_off=pos_off, win_mask=win_mask)
+                    k, v, pm, pos, cache.ring, w, uniform_pos,
+                    slot_off=slot_off, pos_off=pos_off, win_mask=win_mask,
+                    layer=l)
                 h = h + a
                 h, _ = self._mlp_or_moe(lp, h)
-                return h, (kc, vc, pm)
+                return (h, k, v, pm), None
 
-            h, (k, v, pm) = lax.scan(
-                layer, h, (params["layers"], cache.k, cache.v, cache.pos_map))
+            (h, k, v, pm), _ = lax.scan(
+                layer, (h, cache.k, cache.v, cache.pos_map),
+                (params["layers"], jnp.arange(cfg.n_layers)))
             new_cache = AttnCache(k=k, v=v, pos_map=pm, ring=cache.ring)
             return self._logits(params, h), new_cache
 
@@ -615,8 +627,11 @@ class Model:
             return self.verify_step(params, blocks[-1], cache,
                                     pos0 + (n - 1) * chunk, window,
                                     uniform_pos=True)
+        # every row starts at 0: the prompt lands by one dynamic update
+        # slice, not a scatter that would pin the fresh stack's layout
         return self.verify_step(params, tokens, cache, pos0, window,
-                                seq_lens=prompt_lens)
+                                seq_lens=prompt_lens,
+                                uniform_pos=not ring or S <= slots)
 
 
 @functools.partial(jax.jit, static_argnums=0)
