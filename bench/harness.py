@@ -28,7 +28,7 @@ from typing import Optional
 import numpy as np
 
 from bench import traffic, weights
-from bench.spec import Cell, ModelDims
+from bench.spec import Cell, family_of
 
 DRAIN_LIMIT_S = 120.0     # an open loop's last request must retire by then
 WARMUP_LIMIT_S = 900.0    # warm-up serving, compiles included, ends by then
@@ -123,16 +123,6 @@ class Probe:
 
 # -- deployment -----------------------------------------------------------
 
-def model_config(m: ModelDims):
-    from repro.configs.base import ModelConfig
-    return ModelConfig(
-        name=f"bench-{m.name}", arch_type="dense", n_layers=m.layers,
-        d_model=m.d_model, n_heads=m.heads, n_kv_heads=m.kv_heads,
-        d_ff=m.d_ff, vocab=m.vocab, head_dim=m.head_dim, qkv_bias=True,
-        rope_theta=m.rope_theta, norm_eps=m.norm_eps,
-        tie_embeddings=m.tied, dtype=m.dtype)
-
-
 def cluster_spec(cell: Cell, seed: int, max_prompt: int, max_new: int):
     from repro.topology import one_pair_spec
     sv = cell.serving
@@ -173,8 +163,8 @@ def build(cell: Cell, seed: int, seconds: float) -> Built:
     tp = weights.program_params(cell.target, ws, 0)
     dp = tp if cell.self_draft else weights.program_params(cell.draft, ws, 1)
     spec = cluster_spec(cell, seed, max_prompt, max_new)
-    configs = {f"bench-{cell.target.name}": model_config(cell.target),
-               f"bench-{cell.draft.name}": model_config(cell.draft)}
+    configs = {f"bench-{m.name}": family_of(m).program_config(m)
+               for m in (cell.target, cell.draft)}
     dep = build_deployment(spec, model_configs=configs,
                            node_params={"edge0": dp, "cloud0": tp})
     reqs = traffic.generate(cell.traffic, seed, seconds, cell.target.vocab)

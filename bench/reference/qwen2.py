@@ -5,7 +5,8 @@ batching, no kernels, importing nothing of the program. It follows the
 published Qwen2 decoder (hf:Qwen/Qwen2.5-3B): pre-RMSNorm blocks,
 grouped-query attention with q/k/v biases and rotary embeddings
 (rotate-half, base ``rope_theta``), SwiGLU MLP, final RMSNorm and an
-output head tied to the embedding.
+output head tied to the embedding, or untied (``lm_head``). It takes the
+``Dims`` of the family file ``bench/families/qwen2.py``.
 
 The weights are drawn again from the seed, one layer at a time inside a
 ``lax.scan`` (:func:`bench.weights.layer_leaves`), so the whole model is
@@ -25,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from bench.spec import ModelDims
 from bench.weights import global_leaves, layer_leaves
 
 HIGHEST = lax.Precision.HIGHEST
@@ -33,7 +33,8 @@ FP8_MAX = 448.0
 
 # contraction axes of each matrix: the scale is per output channel
 _CONTRACT = {"wq": (0,), "wk": (0,), "wv": (0,), "wo": (0, 1),
-             "w_gate": (0,), "w_up": (0,), "w_down": (0,), "embed": (1,)}
+             "w_gate": (0,), "w_up": (0,), "w_down": (0,), "embed": (1,),
+             "lm_head": (1,)}
 
 
 def _fp8(w, axes):
@@ -69,7 +70,7 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _block(m: ModelDims, h, w):
+def _block(m, h, w):
     """One decoder layer over a batch of sequences h (S, T, d)."""
     t = h.shape[1]
     x = _rms(h, w["ln1"], m.norm_eps)
@@ -95,7 +96,7 @@ def _block(m: ModelDims, h, w):
 
 
 @functools.partial(jax.jit, static_argnums=(0, 4))
-def logits_at(m: ModelDims, key: jax.Array, tokens: jax.Array,
+def logits_at(m, key: jax.Array, tokens: jax.Array,
               out_pos: jax.Array, quant: str = "none") -> jax.Array:
     """Logits (S, n, vocab) of the model drawn from ``key`` over a batch
     of sequences ``tokens`` (S, T), read at positions ``out_pos`` (S, n).
@@ -110,4 +111,5 @@ def logits_at(m: ModelDims, key: jax.Array, tokens: jax.Array,
     h, _ = lax.scan(layer, h, jnp.arange(m.layers))
     h = jnp.take_along_axis(h, out_pos[:, :, None], axis=1)
     x = _rms(h, g["final_norm"], m.norm_eps)
-    return jnp.einsum("snd,vd->snv", x, g["embed"], precision=HIGHEST)
+    head = g["lm_head"] if "lm_head" in g else g["embed"]
+    return jnp.einsum("snd,vd->snv", x, head, precision=HIGHEST)

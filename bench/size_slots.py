@@ -37,7 +37,6 @@ def measure(config: str, traffic_name: str, slots: int) -> dict:
 
     from bench import spec as bspec
     from bench import traffic, weights
-    from bench.harness import model_config
     from repro.core.engine import SpecDecodeEngine
     from repro.core.session import DecodeSession
     from repro.core.specdec import SpecDecodeState
@@ -58,11 +57,16 @@ def measure(config: str, traffic_name: str, slots: int) -> dict:
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
             tree)
 
-    key = weights.model_key(0, 0)
-    tp = abstract(jax.eval_shape(weights._program_params, target, key))
-    dp = tp if self_draft else abstract(
-        jax.eval_shape(weights._program_params, draft, key))
-    eng = SpecDecodeEngine(model_config(draft), model_config(target),
+    def shapes(m):
+        return abstract(jax.eval_shape(bspec.family_of(m).program_params, m,
+                                       weights.model_key(0, 0)))
+
+    def program_config(m):
+        return bspec.family_of(m).program_config(m)
+
+    tp = shapes(target)
+    dp = tp if self_draft else shapes(draft)
+    eng = SpecDecodeEngine(program_config(draft), program_config(target),
                            draft_params=dp, target_params=tp,
                            temperature=0.0, gamma_max=sv["gamma_max"],
                            sync_every=sv["sync_every"])

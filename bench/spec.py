@@ -7,6 +7,8 @@ file of its own, found by name:
   (``bench/configs/<name>.json``), whose models are
   ``bench/models/<model>.json`` (published HF ``config.json`` numbers)
   and whose plain reference is ``bench/reference/<reference>.py``;
+- an architecture: ``bench/families/<family>.py``, named by the
+  ``family`` key of each model file (:func:`family_module`);
 - a traffic mix: ``bench/traffic/<traffic>.json``, read by the one
   generator in :mod:`bench.traffic`;
 - a metric: ``bench/metrics/<name>.py``, or, for a metric split by
@@ -18,8 +20,10 @@ Adding a cell, configuration, mix or metric is adding files and entries.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
+import sys
 from pathlib import Path
 from types import ModuleType
 
@@ -32,65 +36,6 @@ class SpecError(ValueError):
 
 
 @dataclasses.dataclass(frozen=True)
-class ModelDims:
-    """One model at its published widths (HF ``config.json`` names)."""
-    name: str
-    source: str
-    layers: int
-    d_model: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    d_ff: int
-    vocab: int
-    rope_theta: float
-    norm_eps: float
-    tied: bool
-    dtype: str
-
-    @classmethod
-    def load(cls, name: str) -> "ModelDims":
-        path = BENCH / "models" / f"{name}.json"
-        if not path.is_file():
-            raise SpecError(f"no model file {path}")
-        c = json.loads(path.read_text())
-        return cls(name=name, source=c["source"],
-                   layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-                   heads=c["num_attention_heads"],
-                   kv_heads=c["num_key_value_heads"],
-                   head_dim=c.get("head_dim",
-                                  c["hidden_size"] // c["num_attention_heads"]),
-                   d_ff=c["intermediate_size"], vocab=c["vocab_size"],
-                   rope_theta=float(c["rope_theta"]),
-                   norm_eps=float(c["rms_norm_eps"]),
-                   tied=bool(c["tie_word_embeddings"]),
-                   dtype=c["torch_dtype"])
-
-    # -- sizes the work counter and the trace reduction use ---------------
-
-    def layer_params(self) -> int:
-        d, hd = self.d_model, self.head_dim
-        attn = d * hd * (2 * self.heads + 2 * self.kv_heads) \
-            + hd * (self.heads + 2 * self.kv_heads)
-        return attn + 3 * d * self.d_ff + 2 * d
-
-    def params(self) -> int:
-        emb = self.vocab * self.d_model * (1 if self.tied else 2)
-        return emb + self.layers * self.layer_params() + self.d_model
-
-    def matmul_params(self) -> int:
-        """Weights one token multiplies by: every layer's projections and
-        the output head (the embedding lookup is a gather)."""
-        d, hd = self.d_model, self.head_dim
-        per = d * hd * (2 * self.heads + 2 * self.kv_heads) \
-            + 3 * d * self.d_ff
-        return self.layers * per + self.vocab * d
-
-    def kv_bytes_per_position(self, itemsize: int = 2) -> int:
-        return self.layers * 2 * self.kv_heads * self.head_dim * itemsize
-
-
-@dataclasses.dataclass(frozen=True)
 class Cell:
     name: str
     config_name: str
@@ -98,8 +43,8 @@ class Cell:
     chips: int
     config: dict
     traffic: dict
-    target: ModelDims
-    draft: ModelDims          # the target itself for a self-drafting pair
+    target: object            # its family's Dims
+    draft: object             # the target itself for a self-drafting pair
     self_draft: bool
 
     @property
@@ -128,22 +73,35 @@ def load_traffic(name: str) -> dict:
     return json.loads(path.read_text())
 
 
-def config_models(config: dict) -> tuple[ModelDims, ModelDims, bool]:
+def _model_file(name: str) -> dict:
+    path = BENCH / "models" / f"{name}.json"
+    if not path.is_file():
+        raise SpecError(f"no model file {path}")
+    return json.loads(path.read_text())
+
+
+def load_model(name: str):
+    """Model ``name``'s ``Dims``, made by the family its file names."""
+    model = _model_file(name)
+    if "family" not in model:
+        raise SpecError(f"model file {name!r} names no family")
+    return family_module(model["family"]).dims(dict(model, name=name))
+
+
+def config_models(config: dict) -> tuple[object, object, bool]:
     """(target, draft, self_draft) of a configuration file: the target's
-    numbers sit at the top level, the draft is a model file or ``self``."""
-    target = ModelDims.load(config["target_model"])
-    model = json.loads((BENCH / "models" /
-                        f"{config['target_model']}.json").read_text())
-    for key in ("num_hidden_layers", "hidden_size", "intermediate_size",
-                "num_attention_heads", "num_key_value_heads", "vocab_size",
-                "rms_norm_eps", "rope_theta", "tie_word_embeddings",
-                "torch_dtype"):
+    numbers sit at the top level, and every key the configuration shares
+    with the target's model file has to agree with it; the draft is a
+    model file of its own (of any family) or ``self``."""
+    model = _model_file(config["target_model"])
+    for key in sorted(config.keys() & model.keys()):
         if config[key] != model[key]:
             raise SpecError(f"configuration {key}={config[key]!r} differs "
                             f"from the model file's {model[key]!r}")
+    target = load_model(config["target_model"])
     if config["draft_model"] == "self":
         return target, target, True
-    return target, ModelDims.load(config["draft_model"]), False
+    return target, load_model(config["draft_model"]), False
 
 
 def find_cell(workload: str, root: Path = ROOT) -> Cell:
@@ -173,28 +131,51 @@ def cell_metrics(workload: str, trace: bool, root: Path = ROOT
             if "workloads" not in m or workload in m["workloads"]]
 
 
+@functools.lru_cache(maxsize=None)
+def _load(path: Path) -> ModuleType:
+    """The module at ``path``, loaded once."""
+    modname = f"bench_{path.parent.name}_{path.stem}".replace(
+        ".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(modname, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[modname] = mod      # dataclasses look their module up
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def metric_reader(name: str) -> ModuleType:
     """The reader module of metric ``name``: ``metrics/<name>.py``, else
     ``metrics/<base>.py`` for a name split by traffic (``base.suffix``)."""
     for stem in (name, name.split(".", 1)[0]):
         path = BENCH / "metrics" / f"{stem}.py"
         if path.is_file():
-            spec = importlib.util.spec_from_file_location(
-                f"bench_metric_{stem.replace('.', '_').replace('-', '_')}",
-                path)
-            mod = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(mod)
-            return mod
+            return _load(path)
     raise SpecError(f"metric {name!r}: no reader under {BENCH / 'metrics'}")
 
 
-def reference_module(config: dict) -> ModuleType:
-    name = config["reference"]
-    path = BENCH / "reference" / f"{name}.py"
+def _module(kind: str, name: str) -> ModuleType:
+    path = BENCH / kind / f"{name}.py"
     if not path.is_file():
-        raise SpecError(f"no plain reference {path}")
-    spec = importlib.util.spec_from_file_location(f"bench_reference_{name}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+        raise SpecError(f"no {kind} file {path}")
+    return _load(path)
+
+
+def family_module(name: str) -> ModuleType:
+    """The architecture file ``families/<name>.py``. It gives ``dims(model
+    file's numbers) -> Dims`` (frozen and hashable, with ``family``,
+    ``name``, ``source``, ``layers``, ``d_model``, ``vocab``, ``dtype``,
+    ``tied``, and ``params()``, ``kv_bytes(context)``, ``pass_flops`` and
+    ``pass_bytes(tokens, contexts)`` of the needed work, ``shrink(**widths)``),
+    ``leaf_specs(dims)`` and ``global_specs(dims)`` (the leaves
+    :mod:`bench.weights` draws), ``program_params(dims, key)`` (jitted,
+    ``dims`` static) and ``program_config(dims)``."""
+    return _module("families", name)
+
+
+def family_of(m) -> ModuleType:
+    """The family module of a model's ``Dims``."""
+    return family_module(m.family)
+
+
+def reference_module(config: dict) -> ModuleType:
+    return _module("reference", config["reference"])

@@ -24,15 +24,15 @@ TINY_LIMIT = 4e-4
 
 
 def tiny_cell(name: str, slots: int = 4, limit: float = TINY_LIMIT,
-              rate: float = 6.0, self_draft: bool = False) -> spec.Cell:
-    """Cell ``name`` of ``BENCHMARK.json`` at reduced width; with
-    ``self_draft`` its target drafts for itself from one parameter tree
-    (accepted windows and bonus tokens, which random weights between
-    two models never give)."""
-    cell = spec.find_cell(name)
-    target = dataclasses.replace(cell.target, **TINY)
-    draft = target if self_draft else dataclasses.replace(cell.draft,
-                                                          **TINY_DRAFT)
+              rate: float = 6.0, self_draft: bool = False,
+              root=spec.ROOT) -> spec.Cell:
+    """Cell ``name`` of ``root``'s ``BENCHMARK.json`` at reduced width
+    (each model's family's ``shrink``); with ``self_draft`` its target
+    drafts for itself from one parameter tree (accepted windows and bonus
+    tokens, which random weights between two models never give)."""
+    cell = spec.find_cell(name, root)
+    target = cell.target.shrink(**TINY)
+    draft = target if self_draft else cell.draft.shrink(**TINY_DRAFT)
     config = json.loads(json.dumps(cell.config))
     config["serving"]["slots"] = slots
     config["correct"].update(mean_logit_gap=limit, sample_tokens=TINY_SAMPLE)

@@ -1,19 +1,32 @@
-"""The needed-work counter at qwen2.5 widths."""
+"""The needed-work counter at qwen2.5 widths, read through the qwen2
+family (``bench/families/qwen2.py``)."""
 
 import pytest
 
 from bench import work
-from bench.spec import ModelDims
+from bench.spec import load_model
 
-T = ModelDims.load("qwen2.5-3b")
-D = ModelDims.load("qwen2.5-0.5b")
+T = load_model("qwen2.5-3b")
+D = load_model("qwen2.5-0.5b")
 
 
 def test_parameter_counts_match_the_published_models():
     assert T.params() == 3_085_938_688          # "3.09B" on the model card
     assert D.params() == 494_032_768            # "0.49B"
-    assert T.kv_bytes_per_position() == 36 * 2 * 2 * 128 * 2
-    assert D.kv_bytes_per_position() == 24 * 2 * 2 * 64 * 2
+    assert T.kv_bytes(1) == 36 * 2 * 2 * 128 * 2
+    assert D.kv_bytes(1) == 24 * 2 * 2 * 64 * 2
+
+
+@pytest.mark.parametrize("gamma,flops,nbytes", [
+    (0, 19_557_285_888, 6_301_782_016),
+    (1, 42_382_577_664, 7_333_167_104),
+    (12, 293_460_787_200, 18_678_403_072),
+])
+def test_pass_counts_are_the_parents(gamma, flops, nbytes):
+    """The counts the benchmark gave before architectures moved into
+    family files, exactly."""
+    w = work.pass_work(T, D, gamma, [1500, 2000, 37])
+    assert (w.flops, w.bytes, w.passes) == (flops, nbytes, 1)
 
 
 def test_fused_pass_counts_the_target_alone():
@@ -37,7 +50,7 @@ def test_a_window_adds_draft_work_per_decided_token():
                                               for c in ctx))
     assert win.flops - fused.flops == pytest.approx(extra_target + extra_draft)
     assert win.bytes - fused.bytes == pytest.approx(
-        3 * (2 * draft_mm + sum(ctx) * D.kv_bytes_per_position()))
+        3 * (2 * draft_mm + sum(ctx) * D.kv_bytes(1)))
 
 
 def test_a_fused_pass_is_bound_by_weight_bytes():
